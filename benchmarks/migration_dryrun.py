@@ -11,13 +11,16 @@ Two compiled resharding programs over an 8-device elastic axis:
              phases × bucket bytes, exactly the Rödiger-phase schedule the
              planner predicted (the §5 live-migration executor on ICI).
 
-Runs in a subprocess with 8 host devices so the benchmark suite itself
-keeps seeing 1 CPU device.
+Runs in a subprocess with 8 host CPU devices (``JAX_PLATFORMS=cpu``): it
+is an HLO dry run by design, and the parent process may already hold the
+accelerator, which one process owns at a time.
 """
 import json
+import os
 import subprocess
 import sys
-from pathlib import Path
+
+from .common import REPO_ROOT
 
 _CHILD = r"""
 import os
@@ -83,8 +86,9 @@ print(json.dumps(rows))
 
 
 def main():
-    out = subprocess.run([sys.executable, "-c", _CHILD], cwd="/root/repo",
-                         capture_output=True, text=True, timeout=900)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", _CHILD], cwd=REPO_ROOT,
+                         env=env, capture_output=True, text=True, timeout=900)
     if out.returncode != 0:
         print(out.stderr[-3000:])
         raise RuntimeError("migration dryrun child failed")

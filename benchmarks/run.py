@@ -10,6 +10,8 @@ import importlib
 import time
 import traceback
 
+from repro.launch.compile_cache import use_compile_cache
+
 SUITES = [
     ("table1", "Table 1 — motivating sequence example"),
     ("fig4_cost_vs_tau", "Fig. 4 — τ vs migration cost (adhoc/SSM/MTM)"),
@@ -35,6 +37,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     args = ap.parse_args(argv)
+    use_compile_cache()
     only = None
     if args.only:
         only = {name for name in args.only.split(",") if name}

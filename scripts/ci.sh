@@ -6,9 +6,8 @@
 #   scripts/ci.sh lint     — static analysis only (jaxlint + plancheck
 #                            smoke; `make lint`)
 #
-# Runs on a bare jax+numpy+pytest container (the hypothesis property tests
-# fall back to the vendored shim in tests/_vendor); install
-# requirements-dev.txt for full Hypothesis runs.
+# Needs the packages in requirements-dev.txt (jax, numpy, pytest,
+# hypothesis).
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

@@ -1,10 +1,13 @@
-"""jax version compatibility shims.
+"""jax API shims: every version- or context-dependent jax call funnels
+through here.
 
-The repo targets the modern jax API (``jax.shard_map`` with ``axis_names``/
-``check_vma``, ``jax.sharding.get_abstract_mesh``); the container ships jax
-0.4.37 where shard_map lives in ``jax.experimental.shard_map`` with the
-older ``auto=``/``check_rep=`` partial-manual spelling and there is no
-abstract-mesh accessor.  Everything version-dependent funnels through here.
+* ``enable_x64`` — scoped float64 (``jax.enable_x64(True)``); the rest of
+  the process stays float32.
+* ``shard_map`` — ``jax.shard_map`` with partial-manual ``axis_names``.
+* ``ambient_mesh`` — the mesh in scope.  Callers still enter meshes with a
+  legacy ``with mesh:``, which ``jax.sharding.get_abstract_mesh()`` does not
+  see (it returns an empty mesh there); only the thread-resource env holds
+  it.  That fallback stays until the callers move to ``jax.set_mesh``.
 """
 from __future__ import annotations
 
@@ -12,59 +15,37 @@ from typing import Optional
 
 import jax
 
-__all__ = ["shard_map", "get_abstract_mesh", "ambient_mesh"]
+__all__ = ["enable_x64", "shard_map", "ambient_mesh"]
+
+
+def enable_x64():
+    """Context manager: float64 inside, the previous setting restored on
+    exit."""
+    return jax.enable_x64(True)
 
 
 def ambient_mesh():
-    """The mesh currently in scope via ``with mesh:`` (or None)."""
-    try:  # modern API
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and tuple(getattr(m, "axis_names", ()) or ()):
-            return m
-    except AttributeError:
-        pass
-    try:  # jax<=0.4.x: the physical mesh held by the thread resource env
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:  # pragma: no cover
-        pass
+    """The mesh currently in scope via ``jax.set_mesh`` or ``with mesh:``
+    (or None)."""
+    m = jax.sharding.get_abstract_mesh()
+    if m is not None and tuple(getattr(m, "axis_names", ()) or ()):
+        return m
+    # a legacy `with mesh:` is visible only in the thread-resource env
+    from jax._src.mesh import thread_resources
+    m = thread_resources.env.physical_mesh
+    if m is not None and not m.empty:
+        return m
     return None
-
-
-def get_abstract_mesh():
-    """Compat alias for jax.sharding.get_abstract_mesh(); may return None."""
-    return ambient_mesh()
 
 
 def shard_map(f, *, mesh=None, in_specs, out_specs,
               axis_names: Optional[set] = None, check_vma: bool = False):
-    """``jax.shard_map`` with partial-manual axes, on either jax API.
-
-    ``axis_names`` is the set of mesh axes to be manual over (the modern
-    spelling); on jax 0.4.x it is translated to ``auto = mesh axes -
-    axis_names`` for ``jax.experimental.shard_map.shard_map``.  ``mesh``
-    defaults to the ambient mesh.
-    """
-    if hasattr(jax, "shard_map"):  # modern API
-        kwargs = dict(in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if mesh is not None:
-            kwargs["mesh"] = mesh
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kwargs)
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if mesh is None:
-        mesh = ambient_mesh()
-    if mesh is None:
-        raise ValueError("shard_map compat path needs a mesh (explicit or "
-                         "ambient `with mesh:`)")
-    all_axes = set(mesh.axis_names)
-    manual = all_axes if axis_names is None else set(axis_names)
-    auto = frozenset(all_axes - manual)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)
+    """``jax.shard_map``; ``axis_names`` is the set of mesh axes to be
+    manual over, ``mesh`` defaults to the ambient mesh."""
+    kwargs = dict(in_specs=in_specs, out_specs=out_specs,
+                  check_vma=check_vma)
+    if mesh is not None:
+        kwargs["mesh"] = mesh
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kwargs)

@@ -55,11 +55,22 @@ Why this shape is fast on CPU
 * The range-max over contained nodes collapses into one lookup in a tiny
   dense (npad+2)x(npad+1) all-intervals max table, indexed by a p-side
   row base plus an x-side column — one small-table gather, once per call.
-* No argmax is materialized: reconstruction re-derives each optimal
-  transition by exact float64 value-matching against the stored layers
-  (the DP value path contains only IEEE adds/maxes of the very arrays the
-  decoder reads, so equality is bit-exact; any matching transition is a
-  valid optimal continuation).
+* The device returns each state's best transition, not its value.  A
+  TPU's float64 is emulated and not IEEE-exact (inputs lose bits on the
+  way in, adds round differently), so its values cannot be matched
+  against host sums.  The host rebuilds the layer values in IEEE float64
+  from those transitions (``_host_layers``: one vectorized gather + add
+  per layer) and recomputes the full window only for the states the
+  device flags as near-ties (a candidate strictly below the best by at
+  most ``TIE_RTOL`` of it, far above the device's error, which is
+  relative to the nonnegative summands).  Every other state's winner is
+  then the IEEE winner too, so the rebuilt layers equal a CPU run bit for
+  bit, unless two different sums land within the device's resolution of
+  each other without being equal.
+* Reconstruction re-derives each optimal transition by exact float64
+  value-matching against the rebuilt layers (they hold only IEEE
+  adds/maxes of the very arrays the decoder reads, so equality is
+  bit-exact; any matching transition is a valid optimal continuation).
 
 Shape bucketing: small instances (m <= 2048) round m, W and the layer
 count to powers of two so one compilation serves many instances; large
@@ -69,7 +80,7 @@ zero state, which provably leaves the optimum unchanged: cnt[p >= m] := 0
 so padded suffixes are free, and intervals reaching into the padding are
 clamped back to m at decode time with identical gain.
 
-The DP runs in float64 via ``jax.experimental.enable_x64`` (scoped — the
+The DP runs in float64 via ``repro.compat.enable_x64`` (scoped — the
 rest of the process stays float32).
 """
 from __future__ import annotations
@@ -89,6 +100,11 @@ def _pow2(x: int) -> int:
 
 def _ceil_to(x: int, step: int) -> int:
     return ((x + step - 1) // step) * step
+
+
+# near-tie threshold of the device's top-two candidates, relative to
+# max(1, |best|); the device's own error is ~n' * 2^-47 of that
+TIE_RTOL = 1e-9
 
 
 def _allranges_max(fs: np.ndarray) -> np.ndarray:
@@ -135,21 +151,44 @@ def _compiled_dp(mpad: int, W: int, nk: int):
 
             _, (U0, U1, Uc) = jax.lax.scan(unf, None, wis)
 
-            cols = []
+            cols, choice, tie = [], [], []
             for j in (0, 1):
                 totF = jnp.where(FEAS, jnp.where(SEL[j], U1, U0), NEGa)
                 tot1 = G1m[j] + Uc      # invalid entries hold NEG: stay
                 tot2 = G2m[j] + U0      # ~-1e30, never win, never overflow
                 M = jnp.maximum(jnp.maximum(totF, tot1), tot2)
-                red = jnp.max(M, axis=0)                       # [mpad]
+                best = jnp.max(M, axis=0)                      # [mpad]
+                # first wi reaching the best (two plain reductions: a
+                # variadic argmax reduce is several times slower on CPU)
+                bw = jnp.min(jnp.where(M == best[None, :], wis[:, None], W),
+                             axis=0)
+                # the winning kind at bw: filler 0, cand1 1, cand2 2
+                at = [jnp.take_along_axis(t, bw[None], 0)[0]
+                      for t in (totF, tot1)]
+                kind = jnp.where(at[0] == best, 0,
+                                 jnp.where(at[1] == best, 1, 2))
                 tval = jnp.where(cntm <= k, jnp.asarray(0.0, f64), NEGa)
-                cols.append(jnp.maximum(tval, red))
+                # terminal iff nothing gains (values are sums of >= 0 terms)
+                term = (cntm <= k) & (best <= 0)
+                val = jnp.maximum(tval, best)
+                cols.append(val)
+                choice.append(jnp.where(term, -1, kind * W + bw)
+                              .astype(jnp.int32))
+                # a near tie: some candidate strictly below the best but
+                # within TIE_RTOL.  One equal to the best on the device is
+                # a copy of the same value (fillers carry values
+                # unchanged) or the same sum, and gives the same value.
+                lo = val - TIE_RTOL * jnp.maximum(1.0, val)
+                near = (tval >= lo) & (tval < val)
+                for t in (totF, tot1, tot2):
+                    near |= jnp.any((t >= lo) & (t < val), axis=0)
+                tie.append(~term & (val > 0) & near)
             Lk = jnp.concatenate([jnp.stack(cols, axis=1), tail0], axis=0)
-            return Lk, Lk
+            return Lk, (jnp.stack(choice, axis=1), jnp.stack(tie, axis=1))
 
         ks = jnp.arange(1, nk, dtype=jnp.int32)
-        _, Ls = jax.lax.scan(layer, L0, ks)
-        return Ls                                   # [nk-1, LROW, 2]
+        _, (choices, ties) = jax.lax.scan(layer, L0, ks)
+        return choices, ties                        # [nk-1, mpad, 2] each
 
     return jax.jit(dp)
 
@@ -264,12 +303,50 @@ def _pad_inputs(pre: _Pre):
                 L0=L0, sval=sval, zlo_j=zlo_j, ZH1x=ZH1x, ubs_e=ubs_e)
 
 
+def _host_layers(pad, choices: np.ndarray, ties: np.ndarray) -> np.ndarray:
+    """IEEE float64 layer values L[k] (k = 0..nk-1) rebuilt on the host from
+    the device's best transitions: each state's value is its chosen
+    candidate's sum, and near-tied states take the max over the whole
+    window — the same adds and maxes the DP makes, on exact inputs."""
+    mpad, W, nk, LROW = pad["mpad"], pad["W"], pad["nk"], pad["LROW"]
+    FEAS, SEL, G1m, G2m = pad["FEAS"], pad["SEL"], pad["G1m"], pad["G2m"]
+    jp1x, cnt = pad["jp1x"], pad["cnt"]
+    L = np.zeros((nk, LROW, 2))
+    L[0] = pad["L0"]
+    ps = np.arange(mpad)
+    for k in range(1, nk):
+        prev = L[k - 1]
+        tval = np.where(cnt[:mpad] <= k, 0.0, NEG)
+        for j in (0, 1):
+            c = choices[k - 1, :, j].astype(np.int64)
+            kind, wi = np.divmod(np.maximum(c, 0), W)
+            x = ps + 1 + wi
+            vF = np.where(FEAS[wi, ps], prev[x, SEL[j][wi, ps].astype(
+                np.int64)], NEG)
+            v1 = G1m[j][wi, ps] + prev[x, jp1x[x]]
+            v2 = G2m[j][wi, ps] + prev[x, 0]
+            v = np.choose(kind, [vF, v1, v2])
+            v = np.where(c < 0, 0.0, np.maximum(tval, v))
+            tp = np.nonzero(ties[k - 1, :, j])[0]
+            if tp.size:                           # full window, as the DP
+                xs = tp[:, None] + 1 + np.arange(W)[None, :]
+                totF = np.where(FEAS[:, tp].T, prev[xs, SEL[j][:, tp].T
+                                                    .astype(np.int64)], NEG)
+                tot1 = G1m[j][:, tp].T + prev[xs, jp1x[xs]]
+                tot2 = G2m[j][:, tp].T + prev[xs, 0]
+                M = np.maximum(np.maximum(totF, tot1), tot2)
+                v[tp] = np.maximum(tval[tp], M.max(axis=1))
+            L[k, :mpad, j] = v
+    return L
+
+
 def ssm_jit(old: Assignment, w: np.ndarray, s: np.ndarray,
             pre: _Pre) -> MigrationPlan:
     """jit backend entry point; called by ``ssm()`` after the shared
     (backend-independent) feasibility checks have passed."""
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+
+    from ..compat import enable_x64
 
     m, n_new, n_real, n_total = pre.m, pre.n_new, pre.n_real, pre.n_total
     pad = _pad_inputs(pre)
@@ -277,21 +354,21 @@ def ssm_jit(old: Assignment, w: np.ndarray, s: np.ndarray,
     dp = _compiled_dp(mpad, W, nk)
     i32 = np.int32
     with enable_x64():
-        Ls = dp(jnp.asarray(np.stack(pad["G1m"])),
-                jnp.asarray(np.stack(pad["G2m"])),
-                jnp.asarray(np.stack(pad["SEL"])),
-                jnp.asarray(pad["FEAS"]),
-                jnp.asarray(pad["jp1x"].astype(i32)),
-                jnp.asarray(pad["cnt"][:mpad].astype(i32)),
-                jnp.asarray(pad["L0"]))
-        Ls = np.asarray(Ls)                     # [nk-1, LROW, 2]
+        choices, ties = dp(jnp.asarray(np.stack(pad["G1m"])),
+                           jnp.asarray(np.stack(pad["G2m"])),
+                           jnp.asarray(np.stack(pad["SEL"])),
+                           jnp.asarray(pad["FEAS"]),
+                           jnp.asarray(pad["jp1x"].astype(i32)),
+                           jnp.asarray(pad["cnt"][:mpad].astype(i32)),
+                           jnp.asarray(pad["L0"]))
+        choices, ties = np.asarray(choices), np.asarray(ties)
 
-    L = np.concatenate([pad["L0"][None], Ls])   # L[k] = layer k values
+    L = _host_layers(pad, choices, ties)        # L[k] = layer k values
     total_gain = float(L[n_new, 0, 0])
     if total_gain <= NEG / 2:
         raise Infeasible("no feasible solution found")
 
-    # --- reconstruction: exact value-matching against stored layers --------
+    # --- reconstruction: exact value-matching against the rebuilt layers --
     nxt, cnt, NOx, jp1x = pad["nxt"], pad["cnt"], pad["NOx"], pad["jp1x"]
     G1m, G2m = pad["G1m"], pad["G2m"]
     items, full_size = pre.items, pre.full_size

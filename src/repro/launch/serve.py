@@ -8,7 +8,8 @@ Requests are hashed into m buckets (repro.runtime.route); each serving node
 owns a contiguous bucket interval and holds its requests' KV/recurrent rows
 in its own device buffer (``DeviceBucketedState``: per-node cache shards,
 device-to-device when multiple jax devices back the nodes).  Decode runs
-per node on its local shard.  ``--resize-at step:n`` triggers a live
+per node on its local shard, with the weights copied once to each device
+a node decodes on.  ``--resize-at step:n`` triggers a live
 elastic event mid-decode: SSM plans the minimal KV movement from the
 *actual* per-bucket byte sizes, ``MigrationExecutor`` +
 ``JaxBackend`` execute the phases as real row transfers between shards
@@ -24,7 +25,7 @@ from __future__ import annotations
 import argparse
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,8 +33,9 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke
 from repro.core import ElasticPlanner
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import decode_step, init_cache, init_params, prefill
-from repro.roofline import migration_transfer_s
+from repro.roofline import device_peaks, migration_transfer_s
 from repro.runtime import (
     DeviceBucketedState, ElasticController, JaxBackend, MigrationExecutor,
     route, verify_resharding,
@@ -64,10 +66,19 @@ class ServeResult:
         return float(self.step_s[self.resize["step"]])
 
 
-def _decode_nodes(state: DeviceBucketedState, step_fn, params,
-                  tok: np.ndarray, pos_val: int) -> np.ndarray:
+def decode_step_fn(cfg):
+    """The jitted per-node decode step: (params, cache shard, tokens [cap,1],
+    pos [cap]) -> (logits, new shard)."""
+    return jax.jit(lambda p, c, t, pos: decode_step(
+        cfg=cfg, params=p, cache=c, tokens=t, pos=pos))
+
+
+def _decode_nodes(state: DeviceBucketedState, step_fn,
+                  params_on: Callable, tok: np.ndarray,
+                  pos_val: int) -> np.ndarray:
     """One decode step across all serving nodes: each node decodes its own
-    shard (padded rows included, masked out of the result)."""
+    shard (padded rows included, masked out of the result) with the
+    weights held on its own device."""
     new_tok = tok.copy()
     pos = jnp.full((state.cap,), pos_val, jnp.int32)
     for i in state.node_ids():
@@ -76,11 +87,9 @@ def _decode_nodes(state: DeviceBucketedState, step_fn, params,
         if not valid.any():
             continue
         safe = np.where(valid, rows, 0)
-        tok_local = jnp.asarray(tok[safe])
         dev = state.device_of(i)
-        if dev is not None:
-            tok_local = jax.device_put(tok_local, dev)
-        logits, shard = step_fn(params, state.shards[i], tok_local, pos)
+        logits, shard = step_fn(params_on(dev), state.shards[i],
+                                jax.device_put(tok[safe], dev), pos)
         state.shards[i] = shard
         t_local = np.asarray(jnp.argmax(logits, -1).astype(jnp.int32))
         new_tok[rows[valid]] = t_local[valid]
@@ -92,7 +101,9 @@ def _do_resize(ctl: ElasticController, state: DeviceBucketedState,
                verify: bool) -> Dict:
     m = state.m
     w = np.bincount(state.req_bucket, minlength=m).astype(float) + 1e-9
+    t0 = time.perf_counter()
     pre = state.to_host().buckets if verify else None
+    verify_s = time.perf_counter() - t0
     n_before = ctl.n_nodes
     clock0, bytes0 = backend.clock, backend.bytes_moved
     t0 = time.perf_counter()
@@ -103,8 +114,11 @@ def _do_resize(ctl: ElasticController, state: DeviceBucketedState,
                                      state.req_node))
     verified = False
     if verify:
+        t0 = time.perf_counter()
         verify_resharding(plan, state, pre)   # raises on mismatch
+        verify_s += time.perf_counter() - t0
         verified = True
+    peaks = device_peaks(state.device_of(0))
     return {
         "step": step,
         "n_before": n_before,
@@ -115,10 +129,15 @@ def _do_resize(ctl: ElasticController, state: DeviceBucketedState,
         "plan_cost_bytes": float(plan.cost),
         "transfer_s_wall": backend.clock - clock0,
         "resize_s_wall": wall_s,
-        "predicted_ici_s": migration_transfer_s(rep.phase_link_bytes, "ici"),
-        "predicted_hbm_s": migration_transfer_s(rep.phase_link_bytes, "hbm"),
+        "predicted_ici_s": migration_transfer_s(rep.phase_link_bytes,
+                                                "ici", peaks),
+        "predicted_hbm_s": migration_transfer_s(rep.phase_link_bytes,
+                                                "hbm", peaks),
         "routing_ok": routing_ok,
         "verified": verified,
+        "node_devices": [state.device_of(i).id for i in state.node_ids()],
+        # host snapshot + check, kept out of the step time
+        "verify_s_wall": verify_s,
     }
 
 
@@ -128,12 +147,25 @@ def run_serving(arch: str = "qwen2.5-3b", smoke: bool = True,
                 resize: Optional[Tuple[int, int]] = None,
                 tau: float = 0.2, cap: Optional[int] = None,
                 seed: int = 0, verify: bool = True,
+                devices: Optional[Sequence] = None,
                 quiet: bool = True) -> ServeResult:
     """Run the elastic serving loop; ``resize=(step, n_new)`` fires a live
-    mid-decode elastic event that reshards the real KV cache."""
+    mid-decode elastic event that reshards the real KV cache.  Node ``i``
+    lives on ``devices[i % len(devices)]`` (default: all jax devices);
+    prefill runs on ``devices[0]``."""
     cfg = get_smoke(arch) if smoke else get_config(arch)
+    devices = list(devices or jax.devices())
     key = jax.random.PRNGKey(seed)
-    params = init_params(cfg, key)
+    # the weights are made on the first device and copied once to each
+    # other device a node decodes on; no step copies them
+    placed = {devices[0]: jax.device_put(
+        jax.jit(init_params, static_argnums=0)(cfg, key), devices[0])}
+
+    def params_on(dev):
+        if dev not in placed:
+            placed[dev] = jax.device_put(placed[devices[0]], dev)
+        return placed[dev]
+
     B, P, G = requests, prompt_len, gen
     prompts = jax.random.randint(key, (B, P), 0, cfg.vocab_size, jnp.int32)
     batch = {"tokens": prompts}
@@ -156,7 +188,8 @@ def run_serving(arch: str = "qwen2.5-3b", smoke: bool = True,
 
     cache = init_cache(cfg, B, P + G + 1)
     t0 = time.perf_counter()
-    logits, cache = prefill(params, cfg, batch, cache)
+    logits, cache = jax.jit(prefill, static_argnums=1)(
+        placed[devices[0]], cfg, batch, cache)
     tok = np.asarray(jnp.argmax(logits, -1).astype(jnp.int32))
     prefill_s = time.perf_counter() - t0
     if not quiet:
@@ -166,19 +199,20 @@ def run_serving(arch: str = "qwen2.5-3b", smoke: bool = True,
     # operator state the elastic event migrates
     state = DeviceBucketedState.from_cache(
         cache, req_bucket, ctl.assign.owner_of(), cap=cap or B,
-        devices=jax.devices())
+        devices=devices)
     del cache
 
-    step_fn = jax.jit(lambda p, c, t, pos: decode_step(
-        cfg=cfg, params=p, cache=c, tokens=t, pos=pos))
+    step_fn = decode_step_fn(cfg)
     out_tokens = [tok]
     step_s: List[float] = []
     resize_info = None
     for g in range(G):
         t0 = time.perf_counter()
+        verify_s = 0.0
         if resize is not None and g == resize[0]:
             resize_info = _do_resize(ctl, state, backend, resize[1], g,
                                      verify)
+            verify_s = resize_info["verify_s_wall"]
             if not quiet:
                 r = resize_info
                 print(f"  elastic resize @step {g}: n {r['n_before']}→"
@@ -186,8 +220,8 @@ def run_serving(arch: str = "qwen2.5-3b", smoke: bool = True,
                       f"in {r['phases']} phases "
                       f"({r['transfer_s_wall']*1e3:.1f}ms measured, "
                       f"{r['predicted_ici_s']*1e3:.3f}ms roofline ICI)")
-        tok = _decode_nodes(state, step_fn, params, tok, P + g)
-        step_s.append(time.perf_counter() - t0)
+        tok = _decode_nodes(state, step_fn, params_on, tok, P + g)
+        step_s.append(time.perf_counter() - t0 - verify_s)
         out_tokens.append(tok)
     if not quiet:
         dt = sum(step_s)
@@ -219,6 +253,7 @@ def main(argv=None):
     ap.add_argument("--resize-at", default="",
                     help="step:n_new — live elastic event mid-decode")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     resize = None
     if args.resize_at:

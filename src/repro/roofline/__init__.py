@@ -1,11 +1,11 @@
 from .hlo import HloCosts, analyze, parse_computations
 from .terms import (
-    HBM_BW, ICI_BW, PEAK_FLOPS, migration_transfer_s, model_flops,
-    roofline_terms,
+    PEAKS, TARGET_KIND, DevicePeaks, device_peaks, migration_transfer_s,
+    model_flops, roofline_terms,
 )
 
 __all__ = [
     "HloCosts", "analyze", "parse_computations",
-    "HBM_BW", "ICI_BW", "PEAK_FLOPS", "migration_transfer_s", "model_flops",
-    "roofline_terms",
+    "PEAKS", "TARGET_KIND", "DevicePeaks", "device_peaks",
+    "migration_transfer_s", "model_flops", "roofline_terms",
 ]

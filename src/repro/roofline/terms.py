@@ -1,32 +1,63 @@
 """Roofline terms (deliverable g).
 
-Hardware constants (TPU v5e target, per the assignment):
-    peak bf16     197 TFLOP/s per chip
-    HBM bandwidth 819 GB/s per chip
-    ICI           ~50 GB/s per link; a v5e chip has 4 ICI links on the 2D
-                  torus — we charge collectives against ONE link's bandwidth
-                  (conservative; ring collectives stream over one logical
-                  ring unless XLA splits them).
+Hardware peaks come from ``PEAKS``, one row per ``jax.Device.device_kind``
+with its published source.  On a TPU, ``device_peaks()`` returns the row of
+the device present and an unknown kind is an error; a CPU run names the
+v5e row (``TARGET_KIND``) as its target, as does the multi-pod dry run.
+Collectives are charged against ONE ICI link's bandwidth (conservative:
+ring collectives stream over one logical ring unless XLA splits them).
 
 Terms per (arch × shape × mesh), from the loop-aware HLO analysis (all
 per-device quantities — SPMD modules are per-device programs):
 
-    compute_s    = dot_flops / PEAK_FLOPS
-    memory_s     = hbm_bytes / HBM_BW
-    collective_s = collective_bytes / ICI_BW
+    compute_s    = dot_flops / flops
+    memory_s     = hbm_bytes / hbm_bw
+    collective_s = collective_bytes / ici_link_bw
 
 plus MODEL_FLOPS (analytic 6·N·D / 2·N·D useful compute) and the useful /
 compiled compute ratio that catches remat and masked-attention waste.
 """
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Dict
 
 from repro.models.config import ModelConfig, ShapeConfig
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published peaks of one chip."""
+
+    flops: float           # bf16 FLOP/s
+    hbm_bw: float          # HBM bytes/s
+    ici_link_bw: float     # bytes/s on each ICI link
+    ici_links: int
+    source: str
+
+
+PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        flops=197e12, hbm_bw=819e9, ici_link_bw=50e9, ici_links=4,
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip "
+               "(4 links x 50 GB/s)"),
+}
+TARGET_KIND = "TPU v5 lite"
+
+
+def device_peaks(device=None) -> DevicePeaks:
+    """Peaks of ``device`` (default: the first jax device).  A TPU kind
+    missing from ``PEAKS`` raises; other platforms get the v5e target."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return PEAKS[TARGET_KIND]
+    if device.device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device.device_kind!r}; add a row to PEAKS")
+    return PEAKS[device.device_kind]
 
 
 def model_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
@@ -82,31 +113,34 @@ def model_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
     return 2.0 * N_dec * tokens + self_attn_flops(tokens, ctx) + cross
 
 
-def migration_transfer_s(phase_link_bytes, interconnect: str = "ici"
-                         ) -> float:
+def migration_transfer_s(phase_link_bytes, interconnect: str = "ici",
+                         peaks: DevicePeaks = PEAKS[TARGET_KIND]) -> float:
     """Roofline lower bound for a phased state migration.
 
     ``phase_link_bytes``: the busiest-link bytes of each executed phase
     (``MigrationReport.phase_link_bytes``) — a phase ends when its busiest
     link drains, and phases run back-to-back, so the predicted transfer
     time is the sum of per-phase busiest-link bytes over the interconnect
-    bandwidth: ``ici`` for device-to-device resharding (one v5e link,
-    matching the collective accounting above) or ``hbm`` for same-device
-    row copies (gather + scatter both hit HBM, hence the factor 2).
+    bandwidth of ``peaks``: ``ici`` for device-to-device resharding (one
+    link, matching the collective accounting above) or ``hbm`` for
+    same-device row copies (gather + scatter both hit HBM, hence the
+    factor 2).
     """
     if interconnect == "ici":
-        return float(sum(b / ICI_BW for b in phase_link_bytes))
+        return float(sum(b / peaks.ici_link_bw for b in phase_link_bytes))
     if interconnect == "hbm":
-        return float(sum(2.0 * b / HBM_BW for b in phase_link_bytes))
+        return float(sum(2.0 * b / peaks.hbm_bw for b in phase_link_bytes))
     raise ValueError(f"interconnect must be 'ici' or 'hbm', "
                      f"got {interconnect!r}")
 
 
 def roofline_terms(cfg: ModelConfig, shape: ShapeConfig, costs,
-                   n_devices: int) -> Dict[str, float]:
-    compute_s = costs.dot_flops / PEAK_FLOPS
-    memory_s = costs.hbm_bytes / HBM_BW
-    collective_s = costs.collective_bytes / ICI_BW
+                   n_devices: int,
+                   peaks: DevicePeaks = PEAKS[TARGET_KIND]
+                   ) -> Dict[str, float]:
+    compute_s = costs.dot_flops / peaks.flops
+    memory_s = costs.hbm_bytes / peaks.hbm_bw
+    collective_s = costs.collective_bytes / peaks.ici_link_bw
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
@@ -121,7 +155,7 @@ def roofline_terms(cfg: ModelConfig, shape: ShapeConfig, costs,
         "model_flops_total": mf,
         "useful_compute_ratio": (per_dev_useful / costs.dot_flops
                                  if costs.dot_flops else 0.0),
-        "roofline_fraction": (per_dev_useful / PEAK_FLOPS) / total
+        "roofline_fraction": (per_dev_useful / peaks.flops) / total
         if total > 0 else 0.0,
         "step_time_lower_bound_s": total,
     }

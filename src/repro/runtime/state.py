@@ -268,12 +268,12 @@ class DeviceBucketedState:
             shape[ax] = len(req_ids)
             out = np.zeros(shape, leaf.dtype)
             for i, (sel, rows) in parts.items():
-                src = np.asarray(_leaf_at(self.shards[i], path))
-                idx = [slice(None)] * src.ndim
-                idx[ax] = rows
-                odx = [slice(None)] * src.ndim
+                # select the rows on the device: only they cross to the host
+                src = jnp.take(_leaf_at(self.shards[i], path),
+                               jnp.asarray(rows), axis=ax)
+                odx = [slice(None)] * out.ndim
                 odx[ax] = sel
-                out[tuple(odx)] = src[tuple(idx)]
+                out[tuple(odx)] = np.asarray(src)
             return out
 
         return jax.tree_util.tree_map_with_path(build, tpl)

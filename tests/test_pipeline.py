@@ -1,5 +1,7 @@
 """GPipe pipeline tests: schedule correctness (pipeline == sequential),
 transformer-stack equivalence, and the roll→collective-permute lowering."""
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,6 +12,7 @@ from repro.launch.pipeline import (
 )
 
 KEY = jax.random.PRNGKey(0)
+REPO_ROOT = Path(__file__).resolve().parent.parent
 
 
 def test_pipeline_equals_sequential_toy():
@@ -93,7 +96,7 @@ c = analyze(comp.as_text(), 4)
 print(json.dumps({"cp": c.collective_breakdown.get("collective-permute", 0),
                   "counts": c.collective_counts}))
 """
-    out = subprocess.run([sys.executable, "-c", code], cwd="/root/repo",
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO_ROOT,
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-1500:]
     rec = json.loads(out.stdout.strip().splitlines()[-1])

@@ -1,4 +1,6 @@
 """Loop-aware HLO analyzer validation (the roofline's foundation)."""
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +10,8 @@ from jax import lax
 from repro.roofline.hlo import analyze, parse_computations
 from repro.roofline.terms import model_flops
 from repro.models.config import SHAPES
+
+REPO_ROOT = Path(__file__).resolve().parent.parent
 
 
 def _costs(fn, *args):
@@ -85,7 +89,7 @@ c = analyze(txt, 8)
 print(json.dumps({"cb": c.collective_bytes,
                   "counts": c.collective_counts}))
 """
-    out = subprocess.run([sys.executable, "-c", code], cwd="/root/repo",
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO_ROOT,
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-1000:]
     rec = json.loads(out.stdout.strip().splitlines()[-1])
@@ -126,3 +130,35 @@ def test_decode_useful_ratio_near_one_end_to_end():
     n_mm = cfg.n_params() - emb
     expect = 2.0 * n_mm * B
     assert 0.7 * expect < c.dot_flops < 1.6 * expect
+
+
+class _Dev:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_device_peaks_table():
+    from repro.roofline import PEAKS, TARGET_KIND, device_peaks
+    v5e = PEAKS["TPU v5 lite"]
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    # 4 links x 50 GB/s is the published 1,600 Gbit/s per chip
+    assert v5e.ici_links * v5e.ici_link_bw * 8 == 1600e9
+    assert device_peaks(_Dev("tpu", "TPU v5 lite")) is v5e
+    # a CPU run prices against the v5e target row
+    assert device_peaks(_Dev("cpu", "cpu")) is PEAKS[TARGET_KIND] is v5e
+    assert device_peaks() is v5e
+
+
+def test_device_peaks_unknown_tpu_kind_is_an_error():
+    from repro.roofline import device_peaks
+    with pytest.raises(ValueError, match="TPU v99"):
+        device_peaks(_Dev("tpu", "TPU v99"))
+
+
+def test_migration_transfer_s_prices_the_given_peaks():
+    from repro.roofline import PEAKS, migration_transfer_s
+    v5e = PEAKS["TPU v5 lite"]
+    assert migration_transfer_s([50e9, 25e9], "ici", v5e) == 1.5
+    assert migration_transfer_s([819e9], "hbm", v5e) == 2.0
+    with pytest.raises(ValueError):
+        migration_transfer_s([1.0], "pcie", v5e)

@@ -70,3 +70,49 @@ def test_exact_cap_crossing_all_solvers_agree():
     # representation error), not a coin flip per solver
     inst = (old, 2, np.array([5.0, 1.0, 1.0, 1.0]), s, 0.25)
     assert _answer(SOLVERS["simple"], inst) != INFEASIBLE
+
+
+def test_enable_x64_is_scoped():
+    """The DP's float64 scope is restored on exit: the rest of the process
+    stays float32."""
+    import jax.numpy as jnp
+
+    from repro.compat import enable_x64
+    with enable_x64():
+        assert jnp.asarray(np.float64(1.5)).dtype == jnp.float64
+    assert jnp.asarray(np.float64(1.5)).dtype == jnp.float32
+
+
+@pytest.mark.parametrize("m", [64, 1024])
+def test_plan_independent_of_device_rounding(monkeypatch, m):
+    """A TPU's float64 is emulated and not IEEE-exact.  Feed the device DP
+    inputs perturbed by ~1e-13 relative (more than a TPU's rounding): the
+    plan must equal the exact one, since the host rebuilds the layer
+    values in IEEE float64 from the device's transitions."""
+    import jax.numpy as jnp
+
+    from repro.core import ssm_jit
+    from repro.core.ssm import NEG, ssm
+
+    rng = np.random.default_rng(m)
+    old = Assignment.from_boundaries(m, list(np.linspace(0, m, 7).round()
+                                             .astype(int)))
+    inst = (old, 9, rng.uniform(0.2, 2.0, m), rng.uniform(0.1, 3.0, m), 0.4)
+    exact = ssm(*inst, backend="jit")
+
+    real = ssm_jit._compiled_dp
+
+    def inexact(mpad, W, nk):
+        dp = real(mpad, W, nk)
+
+        def run(G1m, G2m, *rest):
+            def fuzz(g):
+                noise = rng.uniform(-1e-13, 1e-13, g.shape)
+                return jnp.where(g > NEG / 2, g * (1 + noise), g)
+            return dp(fuzz(G1m), fuzz(G2m), *rest)
+        return run
+
+    monkeypatch.setattr(ssm_jit, "_compiled_dp", inexact)
+    got = ssm(*inst, backend="jit")
+    assert got.new.intervals == exact.new.intervals
+    assert got.gain == exact.gain
