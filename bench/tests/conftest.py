@@ -1,0 +1,43 @@
+"""Small sizes of the benchmark's configurations, for runs on the CPU.
+
+Run these tests from the repository's root:
+``JAX_PLATFORMS=cpu python3 -m pytest bench/tests``."""
+import dataclasses
+import sys
+import time
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
+
+ARCH = {"qwen25_3b": "qwen2.5-3b", "olmo_1b": "olmo-1b"}
+
+
+def small(name, **widths):
+    """The configuration file at the program's smoke sizes, or at the
+    ``ModelConfig`` sizes given in ``widths``; the program's configuration
+    at those sizes; and a small churn mix."""
+    from bench import harness, traffic
+    from repro.configs import get_smoke
+    cfg = harness.load_json(harness.BENCH / "configs" / f"{name}.json")
+    p = dataclasses.replace(get_smoke(ARCH[name]), **widths)
+    cfg["model"].update(
+        num_hidden_layers=p.n_layers, hidden_size=p.d_model,
+        num_attention_heads=p.n_heads, num_key_value_heads=p.n_kv_heads,
+        intermediate_size=p.d_ff, vocab_size=p.vocab_size,
+        rope_theta=p.rope_theta)
+    mix = traffic.Mix(requests=8, prompt=16, gen=8, buckets=8, cap=6,
+                      tau=0.2, nodes=[2, 4], event_period_s=0.4,
+                      event_phase_s=0.2, sample_responses=6)
+    return cfg, mix, p
+
+
+def run_small(name, seed=2 ** 31 + 5, seconds=1.0, controls=(), trace=False,
+              mix=None, **widths):
+    import jax
+    from bench import counts, harness
+    cfg, small_mix, p = small(name, **widths)
+    return harness.run_cell(
+        cfg, mix or small_mix, seed, seconds, trace, jax.devices()[:1],
+        counts.PEAKS["TPU v5 lite"], time.perf_counter(), pcfg=p,
+        controls=controls, log=lambda m: None)
