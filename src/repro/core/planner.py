@@ -24,6 +24,8 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
+
 from .baselines import adhoc, greedy_trim
 from .intervals import Assignment
 from .mtm import MTM, PMCResult, PartitionTable, mtm_aware_plan, pmc
@@ -112,26 +114,31 @@ class ElasticPlanner:
         s: np.ndarray,
         tau: Optional[float] = None,
     ) -> MigrationPlan:
-        w = np.asarray(w, dtype=np.float64)
-        s = np.asarray(s, dtype=np.float64)
-        n_old = sum(1 for lo, hi in old.intervals if hi > lo)
-        t = self.tau(n_old, n_new) if tau is None else tau
-        if self.policy == "mtm":
-            res = self.fixed_pmc
-            if res is None:
-                res = self.prepare(
-                    w, s, min(n_old, n_new),
-                    max(n_old, n_new,
-                        self.mtm.n_max if self.mtm else n_new), tau=t)
-            return mtm_aware_plan(old, n_new, s, res,
-                                  gain_fn=self.mtm_gain_fn)
-        fn = POLICIES.get(self.policy)
-        if fn is None:
-            raise ValueError(f"unknown policy {self.policy!r}")
-        while True:
-            try:
-                return fn(old, n_new, w, s, t)
-            except Infeasible:
-                if t >= self.relax_tau_max:
-                    raise
-                t = min(t * 1.5 + 0.1, self.relax_tau_max)
+        """A plan by ``policy``; an infeasible τ is relaxed geometrically
+        up to ``relax_tau_max``.  Recorded as a ``plan.search`` span
+        (counter ``attempts``: relaxations + 1)."""
+        with obs.span("plan.search", m=old.m, policy=self.policy):
+            w = np.asarray(w, dtype=np.float64)
+            s = np.asarray(s, dtype=np.float64)
+            n_old = sum(1 for lo, hi in old.intervals if hi > lo)
+            t = self.tau(n_old, n_new) if tau is None else tau
+            if self.policy == "mtm":
+                res = self.fixed_pmc
+                if res is None:
+                    res = self.prepare(
+                        w, s, min(n_old, n_new),
+                        max(n_old, n_new,
+                            self.mtm.n_max if self.mtm else n_new), tau=t)
+                return mtm_aware_plan(old, n_new, s, res,
+                                      gain_fn=self.mtm_gain_fn)
+            fn = POLICIES.get(self.policy)
+            if fn is None:
+                raise ValueError(f"unknown policy {self.policy!r}")
+            while True:
+                obs.count("attempts")
+                try:
+                    return fn(old, n_new, w, s, t)
+                except Infeasible:
+                    if t >= self.relax_tau_max:
+                        raise
+                    t = min(t * 1.5 + 0.1, self.relax_tau_max)

@@ -32,6 +32,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro import obs
+
 from .intervals import (
     Assignment,
     balance_cap,
@@ -353,56 +355,59 @@ def ssm(
     ``simple_ssm`` is the readable O(m²·n·n′) reference at moderate m;
     ``benchmarks/ssm_oracles.py`` runs all four on one instance stream.
     """ % _AUTO_JIT_MIN_M
-    m = old.m
-    if n_new < 1:
-        raise ValueError("n_new >= 1 required")
-    if backend not in ("auto", "numpy", "jit"):
-        raise ValueError(f"unknown ssm backend: {backend!r}")
-    w = np.asarray(w, dtype=np.float64)
-    s = np.asarray(s, dtype=np.float64)
-    Sw, Ss = prefix_sum(w), prefix_sum(s)
-    cap = balance_cap(float(Sw[-1]), n_new, tau)
-    tol = feasible_tol(cap)
-    items = old.nonempty()
-    n_real = len(items)
-    n_total = max(old.n_nodes, n_new)
+    with obs.span("plan.prep"):
+        m = old.m
+        if n_new < 1:
+            raise ValueError("n_new >= 1 required")
+        if backend not in ("auto", "numpy", "jit"):
+            raise ValueError(f"unknown ssm backend: {backend!r}")
+        w = np.asarray(w, dtype=np.float64)
+        s = np.asarray(s, dtype=np.float64)
+        Sw, Ss = prefix_sum(w), prefix_sum(s)
+        cap = balance_cap(float(Sw[-1]), n_new, tau)
+        tol = feasible_tol(cap)
+        items = old.nonempty()
+        n_real = len(items)
+        n_total = max(old.n_nodes, n_new)
 
-    nxt = next_jump(w, cap)
-    if m and (nxt[:-1] <= np.arange(m)).any():
-        raise Infeasible("a single task exceeds the balance cap")
-    cnt = min_cover_counts(nxt)
-    if cnt[0] > n_new:
-        raise Infeasible(f"need >= {cnt[0]} intervals, have {n_new}")
+        nxt = next_jump(w, cap)
+        if m and (nxt[:-1] <= np.arange(m)).any():
+            raise Infeasible("a single task exceeds the balance cap")
+        cnt = min_cover_counts(nxt)
+        if cnt[0] > n_new:
+            raise Infeasible(f"need >= {cnt[0]} intervals, have {n_new}")
 
-    if n_real == 0 or m == 0:
-        # bootstrap: no old state anywhere — greedy split, zero gain.
-        bs = greedy_boundaries(nxt, 0, m)
-        ivs = [(bs[i], bs[i + 1]) for i in range(len(bs) - 1)]
-        ivs += [(m, m)] * (n_new - len(ivs))
-        return _plan(old, Assignment(m, tuple(ivs)).padded(n_total), s)
+        if n_real == 0 or m == 0:
+            # bootstrap: no old state anywhere — greedy split, zero gain.
+            bs = greedy_boundaries(nxt, 0, m)
+            ivs = [(bs[i], bs[i + 1]) for i in range(len(bs) - 1)]
+            ivs += [(m, m)] * (n_new - len(ivs))
+            return _plan(old, Assignment(m, tuple(ivs)).padded(n_total), s)
 
-    lbs = np.array([iv[0] for _, iv in items], dtype=np.int64)
-    ubs = np.array([iv[1] for _, iv in items], dtype=np.int64)
-    full_size = Ss[ubs] - Ss[lbs]
-    # node_of[t] = position (in sorted order) of the old node owning task t
-    node_of = np.zeros(m + 1, dtype=np.int64)
-    for pos in range(n_real):
-        node_of[lbs[pos] : ubs[pos]] = pos
-    node_of[m] = n_real  # sentinel: "past the last node"
+        lbs = np.array([iv[0] for _, iv in items], dtype=np.int64)
+        ubs = np.array([iv[1] for _, iv in items], dtype=np.int64)
+        full_size = Ss[ubs] - Ss[lbs]
+        # node_of[t] = position (in sorted order) of the old node owning task t
+        node_of = np.zeros(m + 1, dtype=np.int64)
+        for pos in range(n_real):
+            node_of[lbs[pos] : ubs[pos]] = pos
+        node_of[m] = n_real  # sentinel: "past the last node"
 
-    # lb_global[x] = minimal lb with weight([lb, x)) <= cap
-    lb_global = min_feasible_starts(Sw, tol, np.arange(m + 1))
+        # lb_global[x] = minimal lb with weight([lb, x)) <= cap
+        lb_global = min_feasible_starts(Sw, tol, np.arange(m + 1))
 
-    pre = _Pre(m=m, n_new=n_new, n_real=n_real, n_total=n_total, Sw=Sw,
-               Ss=Ss, cap=cap, tol=tol, items=items, lbs=lbs, ubs=ubs,
-               full_size=full_size, node_of=node_of, nxt=nxt, cnt=cnt,
-               lb_global=lb_global)
+        pre = _Pre(m=m, n_new=n_new, n_real=n_real, n_total=n_total, Sw=Sw,
+                   Ss=Ss, cap=cap, tol=tol, items=items, lbs=lbs, ubs=ubs,
+                   full_size=full_size, node_of=node_of, nxt=nxt, cnt=cnt,
+                   lb_global=lb_global)
     if backend == "auto":
         backend = "jit" if m >= _AUTO_JIT_MIN_M else "numpy"
+    obs.tag(backend=backend)
     if backend == "jit":
         from . import ssm_jit
         return ssm_jit.ssm_jit(old, w, s, pre)
-    return _ssm_numpy(old, w, s, pre)
+    with obs.span("plan.numpy"):
+        return _ssm_numpy(old, w, s, pre)
 
 
 def _ssm_numpy(old: Assignment, w: np.ndarray, s: np.ndarray,

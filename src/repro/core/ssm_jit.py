@@ -90,6 +90,8 @@ from functools import lru_cache
 import numpy as np
 from numpy.lib.stride_tricks import sliding_window_view
 
+from repro import obs
+
 from .intervals import Assignment, greedy_boundaries, max_feasible_ends
 from .ssm import Infeasible, MigrationPlan, NEG, _plan, _Pre
 
@@ -348,12 +350,12 @@ def ssm_jit(old: Assignment, w: np.ndarray, s: np.ndarray,
 
     from ..compat import enable_x64
 
-    m, n_new, n_real, n_total = pre.m, pre.n_new, pre.n_real, pre.n_total
-    pad = _pad_inputs(pre)
+    with obs.span("plan.tables"):
+        pad = _pad_inputs(pre)
     mpad, W, nk = pad["mpad"], pad["W"], pad["nk"]
-    dp = _compiled_dp(mpad, W, nk)
     i32 = np.int32
-    with enable_x64():
+    with obs.span("plan.dp", mpad=mpad, W=W, nk=nk), enable_x64():
+        dp = _compiled_dp(mpad, W, nk)
         choices, ties = dp(jnp.asarray(np.stack(pad["G1m"])),
                            jnp.asarray(np.stack(pad["G2m"])),
                            jnp.asarray(np.stack(pad["SEL"])),
@@ -362,8 +364,19 @@ def ssm_jit(old: Assignment, w: np.ndarray, s: np.ndarray,
                            jnp.asarray(pad["cnt"][:mpad].astype(i32)),
                            jnp.asarray(pad["L0"]))
         choices, ties = np.asarray(choices), np.asarray(ties)
+        obs.count("near_ties", int(ties.sum()))
 
-    L = _host_layers(pad, choices, ties)        # L[k] = layer k values
+    with obs.span("plan.rebuild"):
+        L = _host_layers(pad, choices, ties)    # L[k] = layer k values
+    with obs.span("plan.decode"):
+        return _decode(old, s, pre, pad, L)
+
+
+def _decode(old: Assignment, s: np.ndarray, pre: _Pre, pad,
+            L: np.ndarray) -> MigrationPlan:
+    """The optimal plan from the rebuilt layers ``L``."""
+    m, n_new, n_real, n_total = pre.m, pre.n_new, pre.n_real, pre.n_total
+    W = pad["W"]
     total_gain = float(L[n_new, 0, 0])
     if total_gain <= NEG / 2:
         raise Infeasible("no feasible solution found")

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs import get_config, get_smoke
 from repro.core import ElasticPlanner
 from repro.launch.compile_cache import use_compile_cache
@@ -78,46 +79,67 @@ def _decode_nodes(state: DeviceBucketedState, step_fn,
                   pos_val: int) -> np.ndarray:
     """One decode step across all serving nodes: each node decodes its own
     shard (padded rows included, masked out of the result) with the
-    weights held on its own device."""
+    weights held on its own device.  Records a ``serve.step`` span with
+    one ``serve.node`` per node decoded, each split into ``serve.dispatch``
+    (host preparation and the step's enqueue) and ``serve.fetch`` (the
+    tokens' argmax and copy to the host, where the step waits for the
+    device)."""
     new_tok = tok.copy()
-    pos = jnp.full((state.cap,), pos_val, jnp.int32)
-    for i in state.node_ids():
-        rows = state.row_req[i]
-        valid = rows >= 0
-        if not valid.any():
-            continue
-        safe = np.where(valid, rows, 0)
-        dev = state.device_of(i)
-        logits, shard = step_fn(params_on(dev), state.shards[i],
-                                jax.device_put(tok[safe], dev), pos)
-        state.shards[i] = shard
-        t_local = np.asarray(jnp.argmax(logits, -1).astype(jnp.int32))
-        new_tok[rows[valid]] = t_local[valid]
+    nodes = rows_live = 0
+    with obs.span("serve.step"):
+        pos = jnp.full((state.cap,), pos_val, jnp.int32)
+        for i in state.node_ids():
+            rows = state.row_req[i]
+            valid = rows >= 0
+            if not valid.any():
+                continue
+            dev = state.device_of(i)
+            with obs.span("serve.node", node=i,
+                          device=getattr(dev, "id", None)):
+                with obs.span("serve.dispatch"):
+                    safe = np.where(valid, rows, 0)
+                    logits, shard = step_fn(params_on(dev), state.shards[i],
+                                            jax.device_put(tok[safe], dev),
+                                            pos)
+                    state.shards[i] = shard
+                with obs.span("serve.fetch"):
+                    t_local = np.asarray(
+                        jnp.argmax(logits, -1).astype(jnp.int32))
+                new_tok[rows[valid]] = t_local[valid]
+            nodes += 1
+            rows_live += int(valid.sum())
+        obs.count("nodes", nodes)
+        obs.count("rows_live", rows_live)
+        obs.count("rows_decoded", nodes * state.cap)
     return new_tok
 
 
 def _do_resize(ctl: ElasticController, state: DeviceBucketedState,
                backend: JaxBackend, n_new: int, step: int,
                verify: bool) -> Dict:
+    """One elastic event: ``ctl.scale`` (its ``elastic.scale`` span is
+    ``resize_s_wall``; the backend's clock, the sum of its
+    ``migrate.phase`` spans, gives ``transfer_s_wall``).  With ``verify``
+    a host snapshot before and ``verify_resharding`` after check the
+    moved state, timed apart (``serve.verify``) as ``verify_s_wall``."""
     m = state.m
     w = np.bincount(state.req_bucket, minlength=m).astype(float) + 1e-9
-    t0 = time.perf_counter()
-    pre = state.to_host().buckets if verify else None
-    verify_s = time.perf_counter() - t0
+    pre, verify_s = None, 0.0
+    if verify:
+        with obs.span("serve.verify") as sp:
+            pre = state.to_host().buckets
+        verify_s += sp.dur_s
     n_before = ctl.n_nodes
     clock0, bytes0 = backend.clock, backend.bytes_moved
-    t0 = time.perf_counter()
     plan, rep = ctl.scale(n_new, w, state)
-    wall_s = time.perf_counter() - t0
+    wall_s = obs.RECORDER.last("elastic.scale").dur_s
     owner = ctl.assign.owner_of()
     routing_ok = bool(np.array_equal(owner[state.req_bucket],
                                      state.req_node))
-    verified = False
     if verify:
-        t0 = time.perf_counter()
-        verify_resharding(plan, state, pre)   # raises on mismatch
-        verify_s += time.perf_counter() - t0
-        verified = True
+        with obs.span("serve.verify") as sp:
+            verify_resharding(plan, state, pre)   # raises on mismatch
+        verify_s += sp.dur_s
     peaks = device_peaks(state.device_of(0))
     return {
         "step": step,
@@ -134,7 +156,7 @@ def _do_resize(ctl: ElasticController, state: DeviceBucketedState,
         "predicted_hbm_s": migration_transfer_s(rep.phase_link_bytes,
                                                 "hbm", peaks),
         "routing_ok": routing_ok,
-        "verified": verified,
+        "verified": verify,
         "node_devices": [state.device_of(i).id for i in state.node_ids()],
         # host snapshot + check, kept out of the step time
         "verify_s_wall": verify_s,

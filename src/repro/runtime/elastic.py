@@ -17,6 +17,7 @@ from typing import List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import (
     Assignment, ElasticPlanner, MigrationPlan, MTM, satisfies_balance,
 )
@@ -112,11 +113,16 @@ class ElasticController:
 
     def scale(self, n_new: int, w: np.ndarray, state: BucketedState,
               tau: Optional[float] = None):
-        plan = self.planner.plan(self.assign, n_new, w,
-                                 state.bucket_bytes(),
-                                 tau=tau if tau is not None else self.tau)
-        return self._apply(plan, state, "scale",
-                           reason=f"requested n={n_new}")
+        """Plan and execute a move to ``n_new`` nodes, recorded as one
+        ``elastic.scale`` span around planning, plan check and transfer."""
+        with obs.span("elastic.scale", n_before=self.n_nodes) as sp:
+            plan = self.planner.plan(self.assign, n_new, w,
+                                     state.bucket_bytes(),
+                                     tau=tau if tau is not None else self.tau)
+            out = self._apply(plan, state, "scale",
+                              reason=f"requested n={n_new}")
+            sp.attrs["n_after"] = self.n_nodes
+        return out
 
     def rebalance(self, w: np.ndarray, state: BucketedState,
                   reason: str = "requested"):

@@ -33,6 +33,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import Assignment, MigrationPlan
 from .state import BucketedState
 
@@ -337,17 +338,13 @@ class SimBackend:
         self.bw = bw_bytes_per_s
         self.clock = 0.0
         self.bytes_moved = 0.0
-        self.phase_log: List[Tuple[float, float]] = []   # (start, end)
 
     def run_phase(self, phase: Sequence[Move], state: BucketedState,
                   placement: np.ndarray):
-        dur = phase_duration(phase, self.bw)
-        start = self.clock
-        self.clock += dur
+        self.clock += phase_duration(phase, self.bw)
         for mv in phase:
             placement[mv.bucket] = mv.dst
             self.bytes_moved += mv.nbytes
-        self.phase_log.append((start, self.clock))
 
 
 class JaxBackend:
@@ -364,9 +361,10 @@ class JaxBackend:
     * host ``BucketedState`` — legacy: whole bucket pytrees are
       ``device_put`` to the destination node's device.
 
-    Same accounting protocol as ``SimBackend`` (``clock`` / ``bytes_moved``
-    / ``phase_log``), except the clock advances by *measured* seconds
-    (``block_until_ready`` around each phase).  ``bw`` is only the
+    Same accounting protocol as ``SimBackend`` (``clock`` /
+    ``bytes_moved``), except the clock advances by *measured* seconds: the
+    duration of each phase's ``migrate.phase`` span, which ends at the
+    phase's ``block_until_ready`` (``migrate.wait``).  ``bw`` is only the
     denominator of the executor's naive-baseline estimate.
     """
 
@@ -376,36 +374,32 @@ class JaxBackend:
         self.bw = bw_bytes_per_s
         self.clock = 0.0
         self.bytes_moved = 0.0
-        self.phase_log: List[Tuple[float, float]] = []
 
     def run_phase(self, phase: Sequence[Move], state,
                   placement: np.ndarray):
-        import time as _time
-
         import jax
-        # JaxBackend's whole point is a *measured* clock (docstring above):
-        # the wall time is reported, never fed back into planning
-        t0 = _time.perf_counter()   # jaxlint: disable=JAX005
-        if hasattr(state, "run_phase"):       # device-resident bucketed view
-            nbytes = state.run_phase(phase)
-        else:                                  # host bucket pytrees
-            nbytes = 0.0
-            moved = []
-            for mv in phase:
-                dev = self.devices[mv.dst % len(self.devices)]
-                state.buckets[mv.bucket] = jax.device_put(
-                    state.buckets[mv.bucket], dev)
-                moved.append(state.buckets[mv.bucket])
-                nbytes += mv.nbytes
-            if moved:
-                jax.block_until_ready(moved)
-        dt = _time.perf_counter() - t0   # jaxlint: disable=JAX005
+        # the measured time is reported, never fed back into planning
+        with obs.span("migrate.phase") as sp:
+            if hasattr(state, "run_phase"):   # device-resident bucketed view
+                nbytes = state.run_phase(phase)
+            else:                              # host bucket pytrees
+                nbytes = 0.0
+                moved = []
+                with obs.span("migrate.dispatch"):
+                    for mv in phase:
+                        dev = self.devices[mv.dst % len(self.devices)]
+                        state.buckets[mv.bucket] = jax.device_put(
+                            state.buckets[mv.bucket], dev)
+                        moved.append(state.buckets[mv.bucket])
+                        nbytes += mv.nbytes
+                obs.count("bytes", nbytes)
+                with obs.span("migrate.wait"):
+                    if moved:
+                        jax.block_until_ready(moved)
         for mv in phase:
             placement[mv.bucket] = mv.dst
-        start = self.clock
-        self.clock += dt
+        self.clock += sp.dur_s
         self.bytes_moved += nbytes
-        self.phase_log.append((start, self.clock))
 
 
 @dataclass
@@ -473,20 +467,23 @@ class MigrationExecutor:
                 phases: Sequence[Sequence[Move]]) -> None:
         # lazy import: analysis imports this module at load time
         from repro.analysis import plancheck
-        findings = plancheck.check_plan(plan, bb)
-        findings += plancheck.check_moves(plan, bb, moves)
-        findings += plancheck.check_schedule(moves, phases, self.mode)
-        findings += plancheck.check_permutation(plan)
+        with obs.span("plan.check"):
+            findings = plancheck.check_plan(plan, bb)
+            findings += plancheck.check_moves(plan, bb, moves)
+            findings += plancheck.check_schedule(moves, phases, self.mode)
+            findings += plancheck.check_permutation(plan)
+            obs.count("findings", len(findings))
         plancheck.handle(findings, self.verify,
                          where=f"MigrationExecutor[{self.mode}]")
 
     def execute(self, plan: MigrationPlan, state: BucketedState,
                 placement: np.ndarray) -> MigrationReport:
-        bb = state.bucket_bytes()
-        moves = move_list(plan, bb)
-        phases = strategy_schedule(moves, bb, self.mode,
-                                   max_inflight=self.max_inflight,
-                                   fluid_batch=self.fluid_batch)
+        with obs.span("migrate.schedule"):
+            bb = state.bucket_bytes()
+            moves = move_list(plan, bb)
+            phases = strategy_schedule(moves, bb, self.mode,
+                                       max_inflight=self.max_inflight,
+                                       fluid_batch=self.fluid_batch)
         if self.verify:
             self._verify(plan, bb, moves, phases)
         t0 = getattr(self.backend, "clock", 0.0)

@@ -21,6 +21,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
+
 try:  # jax is optional at this layer: the sim backend is pure numpy
     import jax
     import jax.numpy as jnp
@@ -211,7 +213,10 @@ class DeviceBucketedState:
         (src, dst) pair, gather the moving buckets' request rows from the
         source shard, transfer them, and scatter into free rows of the
         destination shard.  Returns the bytes actually moved (from real
-        leaf shapes)."""
+        leaf shapes).  Each pair's enqueue is a ``migrate.dispatch`` span
+        (counters ``rows``, ``bytes``), the wait for the destination shards
+        a ``migrate.wait``; the pairs, rows and bytes are also counted on
+        the span around the call (``migrate.phase``)."""
         by_pair: Dict[tuple, List[int]] = {}
         for mv in phase:
             by_pair.setdefault((int(mv.src), int(mv.dst)), []).append(
@@ -219,35 +224,44 @@ class DeviceBucketedState:
         moved = 0.0
         touched = []
         for (src, dst), bkts in sorted(by_pair.items()):
-            reqs = np.concatenate([self.bucket_requests(j) for j in bkts])
-            if len(reqs) == 0:
-                continue
-            if not (self.req_node[reqs] == src).all():
-                raise RuntimeError(
-                    f"buckets {bkts}: rows not on source node {src}")
-            self._ensure_node(dst)
-            src_rows = jnp.asarray(self.req_row[reqs])
-            vals = jax.tree_util.tree_map(
-                lambda leaf, ax: jnp.take(leaf, src_rows, axis=ax),
-                self.shards[src], self._axes)
-            if self.devices:
-                vals = jax.device_put(vals, self.device_of(dst))
-            free = np.nonzero(self.row_req[dst] < 0)[0][: len(reqs)]
-            if len(free) < len(reqs):
-                raise RuntimeError(f"node {dst}: out of row capacity "
-                                   f"({len(reqs)} in, {len(free)} free)")
-            dst_rows = jnp.asarray(free)
-            self.shards[dst] = jax.tree_util.tree_map(
-                lambda leaf, new, ax: _set_rows(leaf, new, ax, dst_rows),
-                self.shards[dst], vals, self._axes)
-            self.row_req[src][self.req_row[reqs]] = -1
-            self.row_req[dst][free] = reqs
-            self.req_node[reqs] = dst
-            self.req_row[reqs] = free
-            moved += len(reqs) * self.row_nbytes
+            with obs.span("migrate.dispatch", src=src, dst=dst):
+                reqs = np.concatenate(
+                    [self.bucket_requests(j) for j in bkts])
+                if len(reqs) == 0:
+                    continue
+                if not (self.req_node[reqs] == src).all():
+                    raise RuntimeError(
+                        f"buckets {bkts}: rows not on source node {src}")
+                self._ensure_node(dst)
+                src_rows = jnp.asarray(self.req_row[reqs])
+                vals = jax.tree_util.tree_map(
+                    lambda leaf, ax: jnp.take(leaf, src_rows, axis=ax),
+                    self.shards[src], self._axes)
+                if self.devices:
+                    vals = jax.device_put(vals, self.device_of(dst))
+                free = np.nonzero(self.row_req[dst] < 0)[0][: len(reqs)]
+                if len(free) < len(reqs):
+                    raise RuntimeError(f"node {dst}: out of row capacity "
+                                       f"({len(reqs)} in, {len(free)} free)")
+                dst_rows = jnp.asarray(free)
+                self.shards[dst] = jax.tree_util.tree_map(
+                    lambda leaf, new, ax: _set_rows(leaf, new, ax, dst_rows),
+                    self.shards[dst], vals, self._axes)
+                self.row_req[src][self.req_row[reqs]] = -1
+                self.row_req[dst][free] = reqs
+                self.req_node[reqs] = dst
+                self.req_row[reqs] = free
+                nbytes = len(reqs) * self.row_nbytes
+                obs.count("rows", len(reqs))
+                obs.count("bytes", nbytes)
+            moved += nbytes
             touched.append(self.shards[dst])
-        if touched:
-            jax.block_until_ready(touched)
+            obs.count("pairs")
+            obs.count("rows", len(reqs))
+            obs.count("bytes", nbytes)
+        with obs.span("migrate.wait"):
+            if touched:
+                jax.block_until_ready(touched)
         return moved
 
     # -- host views ---------------------------------------------------------
