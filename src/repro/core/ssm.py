@@ -343,7 +343,8 @@ def ssm(
       every successor to a one-jump window and removes the sequential task
       loop entirely — layer k reads only layer k−1, so the whole DP is a
       ``lax.scan`` of n′ vectorized sweeps over [window × m] gain tables
-      precomputed host-side.  Shapes are padded into buckets so repeated
+      that the compiled program builds once from O(m + window) host-made
+      1-D tables.  Shapes are padded into buckets so repeated
       plans at similar sizes reuse one compilation.  ~70× faster than numpy
       at m = 10⁴ on one CPU core (see BENCH_ssm.json).
     * ``"auto"``  — ``"jit"`` when m ≥ %d, else ``"numpy"``.

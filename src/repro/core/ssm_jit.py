@@ -47,29 +47,50 @@ Why this shape is fast on CPU
 * With the interval forced to start at p, every quantity in the gain
   formulas is a function of x alone or of p alone, combined by binary
   selects (e.g. Ss[max(lbs[y1(x)], p)] is a select between two 1-D
-  tables).  All [W, mpad] gain/mask matrices are therefore precomputed
-  ONCE per call with numpy stride tricks (zero-copy sliding windows) and
-  reused by every layer; the per-layer work is just: 3 sliding-window
-  unfolds of layer k-1 (built as a scan of ``dynamic_slice`` memcpys — no
-  scalar gathers), 2 adds, 3 selects, 3 maxes and 1 reduction.
-* The range-max over contained nodes collapses into one lookup in a tiny
-  dense (npad+2)x(npad+1) all-intervals max table, indexed by a p-side
-  row base plus an x-side column — one small-table gather, once per call.
+  tables).  The host therefore sends only 1-D tables, x-side ones of
+  length mpad+W+1 and p-side ones of length mpad: O(mpad + W) bytes, not
+  the planes' O(W * mpad).  The compiled program builds the [W, mpad]
+  gain and mask planes from them ONCE per call (``_device_planes``: a
+  scan over the window's rows, each row an unfold of the x tables by one
+  ``dynamic_slice``; the range-max over contained old intervals is
+  carried down the rows, since each row adds at most the one interval
+  ending at its x — no gather, which a TPU does slowly) and every layer
+  reuses them.  The per-layer work is just: 3 sliding-window unfolds of
+  layer k-1 (built the same way), 2 adds, 3 selects, 3 maxes and 1
+  reduction.
 * The device returns each state's best transition, not its value.  A
   TPU's float64 is emulated and not IEEE-exact (inputs lose bits on the
   way in, adds round differently), so its values cannot be matched
-  against host sums.  The host rebuilds the layer values in IEEE float64
-  from those transitions (``_host_layers``: one vectorized gather + add
-  per layer) and recomputes the full window only for the states the
-  device flags as near-ties (a candidate strictly below the best by at
-  most ``TIE_RTOL`` of it, far above the device's error, which is
-  relative to the nonnegative summands).  Every other state's winner is
-  then the IEEE winner too, so the rebuilt layers equal a CPU run bit for
-  bit, unless two different sums land within the device's resolution of
-  each other without being equal.
+  against host sums.  Every mask is therefore decided from integers or
+  host-made booleans: feasibility and the filler's j' from positions,
+  cand1's ``g1 > 0`` from an order code of the prefix sums (``RK``:
+  Ss[a] < Ss[b] iff RK[a] < RK[b], so ``Ss[x] - Ss[a] > 0`` iff RK[x] >
+  RK[a], the IEEE sign of a subtraction being exact), cand2's ``g2 > 0``
+  from whether an old interval of positive size joined the range, or the
+  straddler's sign.  The device marks exactly the entries the host
+  marks; a gain it keeps is floored at ``GAIN_FLOOR`` > 0, so a kept gain
+  is positive on the device as on the host, and so is every sum holding
+  one (the other summands are >= 0).  Only the maximisation sees device
+  rounding.
+* The host rebuilds the layer values in IEEE float64 from the device's
+  transitions (``_host_layers``: one vectorized gather + add per layer,
+  with the entries evaluated pointwise at the chosen (wi, p) by the same
+  ``_entries`` on the host's tables) and recomputes the full window only
+  for the states the device flags as near-ties: a candidate strictly
+  below the best by at most ``TIE_RTOL * max(1, Ss[m])``.  That reference
+  bounds the device's error: the one subtraction the device makes,
+  Ss[x] - Ss[max(lb, p)], errs by a few units of its operands' last
+  place, i.e. relative to Ss[m], however small the difference; each of
+  the <= n' adds of a layer path errs relative to its sum, and every
+  value is a gain <= Ss[m] (a plan keeps at most all state).  So the
+  device's total error is ~n' * 2^-46 * Ss[m], far below the threshold,
+  while max(1, |best|) alone would not cover a gain small next to Ss[m].
+  Every other state's winner is then the IEEE winner too, so the rebuilt
+  layers equal a CPU run bit for bit, unless two different sums land
+  within the device's resolution of each other without being equal.
 * Reconstruction re-derives each optimal transition by exact float64
   value-matching against the rebuilt layers (they hold only IEEE
-  adds/maxes of the very arrays the decoder reads, so equality is
+  adds/maxes of the very entries the decoder reads, so equality is
   bit-exact; any matching transition is a valid optimal continuation).
 
 Shape bucketing: small instances (m <= 2048) round m, W and the layer
@@ -88,7 +109,6 @@ from __future__ import annotations
 from functools import lru_cache
 
 import numpy as np
-from numpy.lib.stride_tricks import sliding_window_view
 
 from repro import obs
 
@@ -105,8 +125,12 @@ def _ceil_to(x: int, step: int) -> int:
 
 
 # near-tie threshold of the device's top-two candidates, relative to
-# max(1, |best|); the device's own error is ~n' * 2^-47 of that
+# max(1, Ss[m]); the device's own error is ~n' * 2^-46 of that
 TIE_RTOL = 1e-9
+
+# the least gain the device keeps: positive in an emulated float64 (whose
+# exponent range may be float32's), and far inside the near-tie threshold
+GAIN_FLOOR = 1e-30
 
 
 def _allranges_max(fs: np.ndarray) -> np.ndarray:
@@ -121,6 +145,74 @@ def _allranges_max(fs: np.ndarray) -> np.ndarray:
     return T
 
 
+def _entries(xp, xv, pv, c2, wi, p, j: int, floor: float = 0.0):
+    """The window entries of DP state (p, j) at successor x = p + 1 + wi:
+    ``FEAS`` ([p, x) is cap-feasible), ``SEL[j]`` (a filler [p, x) leaves
+    j' = 1), ``G1m[j]`` and ``G2m[j]`` (the gain of [p, x) via cand1 and
+    via cand2, NEG where that candidate does not gain).
+
+    ``xv`` holds the x-side tables read at x and ``pv`` the p-side ones,
+    with ``Ss`` and ``RK``, read at p (``_pad_inputs``); ``c2`` is the
+    size of the largest old interval contained in [p, x) among nodes >=
+    zlo_j[p], and whether that is > 0.  ``xp`` is numpy or jax.numpy.  Every mask is
+    integer or boolean; the one float op is cand1's subtraction.  The host
+    evaluates entries pointwise (``floor`` 0: IEEE float64, so identical
+    to building whole planes); the device builds the planes row by row
+    (``_device_planes``) with ``floor=GAIN_FLOOR``."""
+    feas = wi <= pv["fw"]
+    gam = pv["c0"] + j
+    sel = xv["NOx"] < gam
+    # cand1 y1 = node_of(x-1): interval [max(lbs[y1], p), x)
+    own = xv["LB1"] >= p
+    g1 = xv["Ss"] - xp.where(own, xv["SLB1"], pv["Ss"])
+    ok1 = (feas & (xv["Y1"] >= gam)
+           & (xv["RK"] > xp.where(own, xv["RLB1"], pv["RK"])))
+    # cand2: the best contained node; for j = 0 also the straddler at p
+    # once its old interval ends by x
+    g2, ok2 = c2
+    if j == 0:
+        st = pv["ubst"] <= p + 1 + wi
+        g2 = xp.where(st, xp.maximum(g2, pv["sval"]), g2)
+        ok2 = ok2 | st
+    NEGa = xp.asarray(NEG, g1.dtype)
+    G1 = xp.where(ok1, xp.maximum(g1, floor), NEGa)
+    G2 = xp.where(feas & ok2, xp.maximum(g2, floor), NEGa)
+    return feas, sel, G1, G2
+
+
+def _device_planes(xt, pt, mpad: int, W: int):
+    """The [W, mpad] planes FEAS, SEL[j], G1m[j], G2m[j] (j = 0, 1) in
+    jax, from ``_dp_args``' tables: a scan over rows wi, each unfolding
+    the x tables by one slice at x = p + 1 + wi.  cand2's contained range
+    [zlo_j[p], #nodes ending by x) grows by at most the one node ending at
+    x per row, so its max is carried down the rows (no gather): a running
+    max of the same host-made sizes, hence the same value as the host's
+    all-ranges lookup."""
+    import jax
+    import jax.numpy as jnp
+
+    ps = jnp.arange(mpad, dtype=jnp.int32)
+    pv = dict(pt, Ss=xt["Ss"][:mpad], RK=xt["RK"][:mpad])
+    NEGa = jnp.full((mpad,), NEG, xt["Ss"].dtype)
+    no = jnp.zeros((mpad,), bool)
+
+    def row(c2s, wi):
+        xv = {k: jax.lax.dynamic_slice(t, (wi + 1,), (mpad,))
+              for k, t in xt.items()}
+        # the node ending at x joins (state p, j)'s range if >= zlo_j[p]
+        adds = (xv["ZE"] >= pv["zlo0"], xv["ZE"] >= pv["zlo1"])
+        c2s = tuple((jnp.where(add, jnp.maximum(g2, xv["FSE"]), g2),
+                     ok2 | add) for (g2, ok2), add in zip(c2s, adds))
+        e = [_entries(jnp, xv, pv, c2, wi, ps, j, GAIN_FLOOR)
+             for j, c2 in enumerate(c2s)]
+        return c2s, (e[0][0], (e[0][1], e[1][1]), (e[0][2], e[1][2]),
+                     (e[0][3], e[1][3]))
+
+    _, planes = jax.lax.scan(row, ((NEGa, no), (NEGa, no)),
+                             jnp.arange(W, dtype=jnp.int32))
+    return planes
+
+
 @lru_cache(maxsize=64)
 def _compiled_dp(mpad: int, W: int, nk: int):
     """Build + jit the layered one-jump DP for one (mpad, W, nk) bucket."""
@@ -129,12 +221,18 @@ def _compiled_dp(mpad: int, W: int, nk: int):
 
     LROW = mpad + W + 1
 
-    def dp(G1m, G2m, SEL, FEAS, jp1x, cntm, L0):
-        f64 = L0.dtype
+    def dp(xt, pt, jp1x, cnt):
+        f64 = xt["Ss"].dtype
         NEGa = jnp.asarray(NEG, f64)
+        cntm = cnt[:mpad]
+        # layer 0: zero intervals left — done iff the suffix is empty
+        L0 = jnp.repeat(jnp.where(cnt == 0, 0.0, NEGa)[:, None], 2, axis=1)
         tail0 = jnp.zeros((LROW - mpad, 2), f64)
         rows = jnp.arange(LROW, dtype=jnp.int32)
         wis = jnp.arange(W, dtype=jnp.int32)
+        FEAS, SEL, G1m, G2m = _device_planes(xt, pt, mpad, W)
+        # near-tie reference: every value is a gain <= Ss[m]
+        eps = TIE_RTOL * jnp.maximum(1.0, xt["Ss"][-1])
 
         def layer(L1, k):
             # three sliding-window unfolds of layer k-1: U*[wi, p] is the
@@ -170,17 +268,18 @@ def _compiled_dp(mpad: int, W: int, nk: int):
                 kind = jnp.where(at[0] == best, 0,
                                  jnp.where(at[1] == best, 1, 2))
                 tval = jnp.where(cntm <= k, jnp.asarray(0.0, f64), NEGa)
-                # terminal iff nothing gains (values are sums of >= 0 terms)
+                # terminal iff nothing gains (values are sums of >= 0
+                # terms, and a kept gain is > 0 on the device too)
                 term = (cntm <= k) & (best <= 0)
                 val = jnp.maximum(tval, best)
                 cols.append(val)
                 choice.append(jnp.where(term, -1, kind * W + bw)
                               .astype(jnp.int32))
                 # a near tie: some candidate strictly below the best but
-                # within TIE_RTOL.  One equal to the best on the device is
-                # a copy of the same value (fillers carry values
+                # within eps.  One equal to the best on the device is a
+                # copy of the same value (fillers carry values
                 # unchanged) or the same sum, and gives the same value.
-                lo = val - TIE_RTOL * jnp.maximum(1.0, val)
+                lo = val - eps
                 near = (tval >= lo) & (tval < val)
                 for t in (totF, tot1, tot2):
                     near |= jnp.any((t >= lo) & (t < val), axis=0)
@@ -196,8 +295,13 @@ def _compiled_dp(mpad: int, W: int, nk: int):
 
 
 def _pad_inputs(pre: _Pre):
-    """Pad into a shape bucket and precompute the k-independent gain and
-    mask matrices (host-side numpy; zero-copy sliding windows).
+    """Pad into a shape bucket and build the DP's 1-D tables (host numpy,
+    O(mpad + W)): the x-side tables ``xt`` (length LROW = mpad + W + 1,
+    read at successor x) and the p-side tables ``pt`` (length mpad, read
+    at state p), which the device gets; ``_entries`` makes every [W, mpad]
+    gain and mask entry from them.  The host's own lookups of cand2's
+    contained-range max use the all-intervals table ``PM2`` at (zlo_j[p],
+    ``ZH1x[x]``).
 
     Padding tasks (index >= m) have zero weight and zero state: they extend
     the last feasible jump for free, add no gain anywhere, and cnt[p >= m]
@@ -236,7 +340,7 @@ def _pad_inputs(pre: _Pre):
         cnt[a] = 1 + cnt[nxt[a]]
     cnt = np.minimum(cnt, nk)
 
-    # -- 1-D tables over x in [0, LROW) and p in [0, mpad) ------------------
+    # -- x-side tables over x in [0, LROW) ----------------------------------
     NOx = np.full(LROW, n_real, dtype=np.int64)        # node containing x
     NOx[: m + 1] = pre.node_of
     NOx[m:] = n_real
@@ -251,17 +355,29 @@ def _pad_inputs(pre: _Pre):
     Ssx = np.empty(LROW, dtype=np.float64)             # Ss at clamped x
     Ssx[: mpad + 1] = Ss_pad
     Ssx[mpad:] = Ss_pad[-1]
+    # order code: Ssx[a] < Ssx[b] iff RK[a] < RK[b]
+    RK = np.unique(Ssx, return_inverse=True)[1].reshape(-1)
     Y1x = np.empty(LROW, dtype=np.int64)               # node_of[x-1]
     Y1x[1:] = NOx[:-1]
     Y1x[0] = 0
     y1c = np.minimum(Y1x, npad - 1)
     LB1x = lbs_e[y1c]                                  # lbs[node_of[x-1]]
-    SS_LB1x = Ssx[np.minimum(LB1x, mpad)]
+    lb1c = np.minimum(LB1x, mpad)
     jp1x = np.clip(Y1x + 1 - NOx, 0, 1)                # cand1 j' plane
     ZH1x = np.where((NOx < n_real) & (ubs_e[np.minimum(NOx, npad - 1)]
                                       <= np.arange(LROW)),
                     NOx, NOx - 1) + 1                  # contained hi + 1
+    # the node ending at x (nodes are nonempty: at most one), -1 where
+    # none or where it holds no state, and its size
+    ends = np.diff(ZH1x, prepend=0)
+    assert ends.max(initial=0) <= 1, "two old intervals end at one x"
+    ZE = np.where((ends > 0) & (fs[np.maximum(ZH1x - 1, 0)] > 0),
+                  ZH1x - 1, -1)
+    xt = dict(NOx=NOx, Y1=np.where(Y1x < n_real, Y1x, -1), LB1=LB1x,
+              SLB1=Ssx[lb1c], Ss=Ssx, RK=RK, RLB1=RK[lb1c], ZE=ZE,
+              FSE=np.where(ZE >= 0, fs[np.maximum(ZE, 0)], NEG))
 
+    # -- p-side tables over p in [0, mpad) ----------------------------------
     parange = np.arange(mpad)
     c0 = NOx[:mpad]                                    # node containing p
     c0c = np.minimum(c0, npad - 1)
@@ -269,40 +385,36 @@ def _pad_inputs(pre: _Pre):
     sval = Ssx[np.minimum(ubs_e[c0c], mpad)] - \
         Ssx[np.maximum(np.minimum(lbs_e[c0c], mpad), parange)]
     zlo0 = np.where((c0 < n_real) & (lbs_e[c0c] >= parange), c0, c0 + 1)
-    zlo_j = [np.maximum(zlo0, c0 + j) for j in (0, 1)]
-
-    # -- [W, mpad] gain/mask matrices (row wi <-> successor x = p+1+wi) -----
-    def unf(T):      # rows wi = T[1+wi : 1+wi+mpad]  (zero-copy view)
-        return sliding_window_view(T, mpad)[1 : W + 1]
-
-    wi_col = np.arange(W, dtype=np.int64)[:, None]
-    FEAS = wi_col <= (nxt[:mpad] - parange - 1)[None, :]
-    Xu = wi_col + parange[None, :] + 1
-    Y1u = unf(Y1x)
-    g1 = unf(Ssx) - np.where(unf(LB1x) >= parange[None, :],
-                             unf(SS_LB1x), Ss_pad[:mpad][None, :])
-    G1m, G2m, SEL = [], [], []
-    idx_x = unf(ZH1x)
-    for j in (0, 1):
-        gam = (c0 + j)[None, :]
-        v1 = FEAS & (Y1u >= gam) & (Y1u < n_real) & (g1 > 0)
-        G1m.append(np.where(v1, g1, NEG))
-        # contained-range max: one lookup in the tiny all-ranges table,
-        # row base from the p side, column from the x side
-        g2 = np.take(PM2.reshape(-1),
-                     zlo_j[j][None, :] * (npad + 1) + idx_x)
-        if j == 0:
-            s_ok = (c0 < n_real)[None, :] & (ubs_e[c0c][None, :] <= Xu)
-            g2 = np.maximum(g2, np.where(s_ok, sval[None, :], NEG))
-        G2m.append(np.where(FEAS & (g2 > 0), g2, NEG))
-        SEL.append(unf(NOx) < gam)                    # filler j' == 1
-
-    # layer 0: zero intervals left — done iff the suffix is already empty
-    L0 = np.where((cnt == 0)[:, None], 0.0, NEG).repeat(2, axis=1)
+    pt = dict(fw=nxt[:mpad] - parange - 1, c0=c0, sval=sval,
+              # the straddler gains for x >= ubst (never where it is empty)
+              ubst=np.where((c0 < n_real) & (sval > 0), ubs_e[c0c], LROW),
+              zlo0=zlo0, zlo1=np.maximum(zlo0, c0 + 1))
 
     return dict(mpad=mpad, W=W, nk=nk, LROW=LROW, nxt=nxt, cnt=cnt,
-                NOx=NOx, jp1x=jp1x, G1m=G1m, G2m=G2m, SEL=SEL, FEAS=FEAS,
-                L0=L0, sval=sval, zlo_j=zlo_j, ZH1x=ZH1x, ubs_e=ubs_e)
+                NOx=NOx, jp1x=jp1x, xt=xt, pt=pt, ZH1x=ZH1x, PM2=PM2,
+                ubs_e=ubs_e)
+
+
+def _dp_args(pad) -> tuple:
+    """``_compiled_dp``'s arguments, as the host copies them to the device:
+    int32 and float64, O(mpad + W) bytes in all."""
+    def dev(a):
+        return a.astype(np.int32) if a.dtype.kind in "iu" else a
+
+    return ({k: dev(t) for k, t in pad["xt"].items()},
+            {k: dev(t) for k, t in pad["pt"].items()},
+            dev(pad["jp1x"]), dev(pad["cnt"]))
+
+
+def _entries_at(pad, wi, p, j: int):
+    """``_entries`` on the host at broadcastable index arrays (wi, p)."""
+    x = p + 1 + wi
+    xv = {k: t[x] for k, t in pad["xt"].items()}
+    pv = {k: t[p] for k, t in pad["pt"].items()}
+    pv.update(Ss=pad["xt"]["Ss"][p], RK=pad["xt"]["RK"][p])
+    # the contained-range max: one lookup in the all-ranges table
+    g2 = pad["PM2"][pv["zlo1"] if j else pv["zlo0"], pad["ZH1x"][x]]
+    return _entries(np, xv, pv, (g2, g2 > 0), wi, p, j)
 
 
 def _host_layers(pad, choices: np.ndarray, ties: np.ndarray) -> np.ndarray:
@@ -311,10 +423,9 @@ def _host_layers(pad, choices: np.ndarray, ties: np.ndarray) -> np.ndarray:
     candidate's sum, and near-tied states take the max over the whole
     window — the same adds and maxes the DP makes, on exact inputs."""
     mpad, W, nk, LROW = pad["mpad"], pad["W"], pad["nk"], pad["LROW"]
-    FEAS, SEL, G1m, G2m = pad["FEAS"], pad["SEL"], pad["G1m"], pad["G2m"]
     jp1x, cnt = pad["jp1x"], pad["cnt"]
     L = np.zeros((nk, LROW, 2))
-    L[0] = pad["L0"]
+    L[0] = np.where(cnt == 0, 0.0, NEG)[:, None]
     ps = np.arange(mpad)
     for k in range(1, nk):
         prev = L[k - 1]
@@ -323,19 +434,20 @@ def _host_layers(pad, choices: np.ndarray, ties: np.ndarray) -> np.ndarray:
             c = choices[k - 1, :, j].astype(np.int64)
             kind, wi = np.divmod(np.maximum(c, 0), W)
             x = ps + 1 + wi
-            vF = np.where(FEAS[wi, ps], prev[x, SEL[j][wi, ps].astype(
-                np.int64)], NEG)
-            v1 = G1m[j][wi, ps] + prev[x, jp1x[x]]
-            v2 = G2m[j][wi, ps] + prev[x, 0]
+            feas, sel, g1, g2 = _entries_at(pad, wi, ps, j)
+            vF = np.where(feas, prev[x, sel.astype(np.int64)], NEG)
+            v1 = g1 + prev[x, jp1x[x]]
+            v2 = g2 + prev[x, 0]
             v = np.choose(kind, [vF, v1, v2])
             v = np.where(c < 0, 0.0, np.maximum(tval, v))
             tp = np.nonzero(ties[k - 1, :, j])[0]
             if tp.size:                           # full window, as the DP
-                xs = tp[:, None] + 1 + np.arange(W)[None, :]
-                totF = np.where(FEAS[:, tp].T, prev[xs, SEL[j][:, tp].T
-                                                    .astype(np.int64)], NEG)
-                tot1 = G1m[j][:, tp].T + prev[xs, jp1x[xs]]
-                tot2 = G2m[j][:, tp].T + prev[xs, 0]
+                wis = np.arange(W)[None, :]
+                xs = tp[:, None] + 1 + wis
+                feas, sel, g1, g2 = _entries_at(pad, wis, tp[:, None], j)
+                totF = np.where(feas, prev[xs, sel.astype(np.int64)], NEG)
+                tot1 = g1 + prev[xs, jp1x[xs]]
+                tot2 = g2 + prev[xs, 0]
                 M = np.maximum(np.maximum(totF, tot1), tot2)
                 v[tp] = np.maximum(tval[tp], M.max(axis=1))
             L[k, :mpad, j] = v
@@ -346,6 +458,7 @@ def ssm_jit(old: Assignment, w: np.ndarray, s: np.ndarray,
             pre: _Pre) -> MigrationPlan:
     """jit backend entry point; called by ``ssm()`` after the shared
     (backend-independent) feasibility checks have passed."""
+    import jax
     import jax.numpy as jnp
 
     from ..compat import enable_x64
@@ -353,16 +466,12 @@ def ssm_jit(old: Assignment, w: np.ndarray, s: np.ndarray,
     with obs.span("plan.tables"):
         pad = _pad_inputs(pre)
     mpad, W, nk = pad["mpad"], pad["W"], pad["nk"]
-    i32 = np.int32
-    with obs.span("plan.dp", mpad=mpad, W=W, nk=nk), enable_x64():
+    args = _dp_args(pad)
+    in_bytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(args))
+    with obs.span("plan.dp", mpad=mpad, W=W, nk=nk, in_bytes=in_bytes), \
+            enable_x64():
         dp = _compiled_dp(mpad, W, nk)
-        choices, ties = dp(jnp.asarray(np.stack(pad["G1m"])),
-                           jnp.asarray(np.stack(pad["G2m"])),
-                           jnp.asarray(np.stack(pad["SEL"])),
-                           jnp.asarray(pad["FEAS"]),
-                           jnp.asarray(pad["jp1x"].astype(i32)),
-                           jnp.asarray(pad["cnt"][:mpad].astype(i32)),
-                           jnp.asarray(pad["L0"]))
+        choices, ties = dp(*jax.tree_util.tree_map(jnp.asarray, args))
         choices, ties = np.asarray(choices), np.asarray(ties)
         obs.count("near_ties", int(ties.sum()))
 
@@ -383,7 +492,7 @@ def _decode(old: Assignment, s: np.ndarray, pre: _Pre, pad,
 
     # --- reconstruction: exact value-matching against the rebuilt layers --
     nxt, cnt, NOx, jp1x = pad["nxt"], pad["cnt"], pad["NOx"], pad["jp1x"]
-    G1m, G2m = pad["G1m"], pad["G2m"]
+    pt = pad["pt"]
     items, full_size = pre.items, pre.full_size
     nxt_real = np.minimum(nxt[: m + 1], m)
     new_ivs: list = [(m, m)] * n_total
@@ -410,24 +519,25 @@ def _decode(old: Assignment, s: np.ndarray, pre: _Pre, pad,
             j = 1 if NOx[q] < gamma else 0
             x0, k = q, k - 1
             continue
-        tot1 = G1m[j][wis, x0] + prev[xs, jp1x[xs]]
+        _, _, g1, g2 = _entries_at(pad, wis, x0, j)
+        tot1 = g1 + prev[xs, jp1x[xs]]
         hit1 = np.nonzero(tot1 == Gv)[0]
         if hit1.size:                                  # gain via cand1
             x = x0 + 1 + int(hit1[0])
             y = int(NOx[x - 1])
         else:                                          # gain via cand2
-            tot2 = G2m[j][wis, x0] + prev[xs, 0]
+            tot2 = g2 + prev[xs, 0]
             hit2 = np.nonzero(tot2 == Gv)[0]
             assert hit2.size, "decode: no transition matches the DP value"
             x = x0 + 1 + int(hit2[0])
-            g2v = float(G2m[j][x - x0 - 1, x0])
+            g2v = float(g2[x - x0 - 1])
             c0 = int(NOx[x0])
             y = -1
             if (j == 0 and c0 < n_real and int(pad["ubs_e"][c0]) <= x
-                    and float(pad["sval"][x0]) == g2v):
+                    and float(pt["sval"][x0]) == g2v):
                 y = c0                                 # straddler at x0
             else:
-                zlo = int(pad["zlo_j"][j][x0])
+                zlo = int(pt["zlo1" if j else "zlo0"][x0])
                 zhi = int(pad["ZH1x"][x]) - 1
                 assert 0 <= zlo <= zhi < n_real, "decode: empty cand2 range"
                 sub = full_size[zlo : zhi + 1]

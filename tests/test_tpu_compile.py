@@ -11,6 +11,8 @@ Left out: the ``interval_gain``, ``decode_attention``, ``rglru_scan`` and
 D8); no entry point runs them.
 """
 import os
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +25,8 @@ from repro.core.ssm_jit import _compiled_dp
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.launch.serve import decode_step_fn
 from repro.models import init_cache, init_params
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 V5E_HBM_BYTES = 16 * 2**30
 
@@ -85,19 +89,23 @@ def test_decode_step_full_width_fits_one_v5e(one_chip):
     assert 0 < n <= V5E_HBM_BYTES, n
 
 
-def test_ssm_dp_m10k_bucket_compiles_x64(one_chip):
-    LROW = SSM_MPAD + SSM_W + 1
-    f64 = jnp.float64
-    shapes = [((2, SSM_W, SSM_MPAD), f64), ((2, SSM_W, SSM_MPAD), f64),
-              ((2, SSM_W, SSM_MPAD), jnp.bool_), ((SSM_W, SSM_MPAD),
-                                                  jnp.bool_),
-              ((LROW,), jnp.int32), ((SSM_MPAD,), jnp.int32),
-              ((LROW, 2), f64)]
+def test_ssm_dp_m10k_bucket_compiles_x64(one_chip, monkeypatch):
+    # the DP's real arguments: the host's 1-D tables of the fig5 instance
+    from benchmarks.fig5_ssm_runtime import scaling_instance
+    from repro.core import ssm_jit
+    from repro.core.ssm import ssm
+
+    seen = []
+    monkeypatch.setattr(ssm_jit, "ssm_jit",
+                        lambda old, w, s, pre: seen.append(pre))
+    ssm(*scaling_instance(10_000, 12, 16, 0.4, 0), backend="jit")
+    pad = ssm_jit._pad_inputs(seen[0])
+    assert (pad["mpad"], pad["W"], pad["nk"]) == (SSM_MPAD, SSM_W, SSM_NK)
+    args = ssm_jit._dp_args(pad)
+    assert sum(a.nbytes for a in jax.tree_util.tree_leaves(args)) < 2**20
     with enable_x64():
-        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-                for s, d in shapes]
         compiled = _compiled_dp(SSM_MPAD, SSM_W, SSM_NK).lower(
-            *args).compile()
+            *_on(one_chip, args)).compile()
     choices, ties = compiled.out_info
     assert choices.shape == ties.shape == (SSM_NK - 1, SSM_MPAD, 2)
     assert choices.dtype == jnp.int32 and ties.dtype == jnp.bool_
