@@ -10,6 +10,10 @@ configuration's own limits:
 - the exchange between nodes left out: an event's rows are booked on
   their new node but never copied there;
 - a token altered where it is produced.
+
+Each runs on one chip for both configurations, and for qwen2.5-3b also on
+four (four CPU devices, 1 <-> 4 nodes), where the exchange left out is
+the one between chips.
 """
 import jax
 import jax.numpy as jnp
@@ -20,14 +24,25 @@ from repro.runtime.state import DeviceBucketedState
 
 from conftest import run_small
 
-CONFIGS = ["qwen25_3b", "olmo_1b"]
+CONFIGS = ["qwen25_3b", "olmo_1b",
+           pytest.param(("qwen25_3b", 4), id="qwen25_3b-4chips")]
+
+
+def run_case(case):
+    name, chips = case if isinstance(case, tuple) else (case, 1)
+    out = run_small(name, chips=chips)
+    assert len(out["run"].device_ids) == chips
+    return out
 
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_sound_run_is_correct(name):
-    out = run_small(name)
+    out = run_case(name)
     assert out["correct"], out["checks"]
-    assert out["run"].events and out["run"].compiles_in_window == 0
+    run = out["run"]
+    assert run.events and run.compiles_in_window == 0
+    across = {src != dst for e in run.events for src, dst, _ in e["row_moves"]}
+    assert across == ({True} if len(run.device_ids) > 1 else {False})
 
 
 @pytest.mark.parametrize("name", CONFIGS)
@@ -39,7 +54,7 @@ def test_state_left_unchanged(name, monkeypatch):
         return lambda p, c, t, pos: (step(p, c, t, pos)[0], c)
 
     monkeypatch.setattr(serve, "decode_step_fn", stale)
-    assert not run_small(name)["correct"]
+    assert not run_case(name)["correct"]
 
 
 @pytest.mark.parametrize("name", CONFIGS)
@@ -52,7 +67,7 @@ def test_half_the_batch_left_out(name, monkeypatch):
         return out
 
     monkeypatch.setattr(serve, "_decode_nodes", half)
-    assert not run_small(name)["correct"]
+    assert not run_case(name)["correct"]
 
 
 @pytest.mark.parametrize("name", CONFIGS)
@@ -69,7 +84,7 @@ def test_exchange_left_out(name, monkeypatch):
         return moved
 
     monkeypatch.setattr(DeviceBucketedState, "run_phase", no_copy)
-    out = run_small(name)
+    out = run_case(name)
     assert out["run"].events and not out["correct"]
 
 
@@ -86,4 +101,4 @@ def test_token_altered(name, monkeypatch):
         return out
 
     monkeypatch.setattr(serve, "_decode_nodes", altered)
-    assert not run_small(name)["correct"]
+    assert not run_case(name)["correct"]

@@ -110,3 +110,54 @@ def test_readers_find_nothing_to_read():
                     run.model.decode_min_bytes(run.sizes, 32, s.ctx) / 819e9)
                 for s in run.steps)
     assert read("decode_roofline", run) == pytest.approx(100 * least / 0.5)
+
+
+# ---------------------------------------------------------------------------
+# which cells report which metrics
+# ---------------------------------------------------------------------------
+
+CHURN = {"token_gap_ms_p95", "resize_ms", "peak_hbm_bytes", "setup_s"}
+STEADY = {"tokens_per_s", "token_gap_ms_p95", "peak_hbm_bytes", "setup_s"}
+
+
+def reported(cell, trace=False):
+    """The metric names a result line of ``cell`` carries."""
+    return {m["name"] for m in harness.metric_entries(SPEC, cell, trace)}
+
+
+def test_every_per_layer_metric_names_its_cells():
+    for m in SPEC["per_layer"]:
+        assert m["workloads"]
+        for cell in m["workloads"]:     # each reports what the metric moves
+            assert m["moves"] in reported(cell), (m["name"], cell)
+
+
+def test_a_cell_in_no_list_gets_the_unlisted_metrics():
+    assert reported("no_such.cell") == {
+        m["name"] for m in SPEC["end_to_end"] if "workloads" not in m}
+    assert reported("no_such.cell", trace=True) == set()
+
+
+@pytest.mark.parametrize("cell, names", [
+    ("qwen25_3b.churn", CHURN),
+    ("olmo_1b.churn_m4096", CHURN),
+    ("qwen25_3b.steady", STEADY),
+    ("qwen25_3b.churn_4chip", CHURN),
+])
+def test_cells_report_their_end_to_end_metrics(cell, names):
+    assert reported(cell) == names
+
+
+def test_four_chip_cells_within_limit():
+    four = [w for w in SPEC["workloads"] if w["chips"] == 4]
+    assert all(w["chips"] in (1, 4) for w in SPEC["workloads"])
+    assert len(four) <= max(1, len(SPEC["workloads"]) // 2)
+
+
+def test_the_four_chip_mix():
+    mix = traffic.Mix.load("churn_4chip")
+    assert (mix.requests, mix.prompt, mix.gen, mix.buckets, mix.cap,
+            mix.tau, mix.nodes, mix.event_period_s, mix.event_phase_s,
+            mix.sample_responses) == (32, 1024, 64, 32, 32, 0.2, [1, 4],
+                                      5.0, 2.5, 8)
+    assert len(mix.events(SPEC["run_seconds"])) == 10
