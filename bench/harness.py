@@ -11,12 +11,20 @@ The benchmark's interface into the program (the system under test):
 
 - ``repro.configs.get_config``, ``repro.models.init_params`` (shapes only,
   to check the weights' layout), ``init_cache`` and ``prefill``;
-- ``repro.runtime``: ``route``, ``DeviceBucketedState.from_cache``,
-  ``ElasticController`` with ``ElasticPlanner(policy="ssm")`` and
-  ``MigrationExecutor(JaxBackend(), mode="live", verify="strict")``;
+- ``repro.runtime``: ``route``, ``DeviceBucketedState.from_cache`` and
+  its ``shards``, ``row_req`` and ``device_of``, ``ElasticController``
+  with ``ElasticPlanner(policy="ssm")`` and ``MigrationExecutor(
+  JaxBackend(), mode="live", verify="strict")``;
+- ``repro.runtime.state.cache_batch_axes`` (the request axis of each
+  cache leaf, for the restart's snapshot);
 - ``repro.launch.serve``: ``decode_step_fn``, ``_decode_nodes`` (one
   decode step over every node) and ``_do_resize`` (one elastic event:
   controller -> planner -> plan check -> executor -> device state).
+
+Every response restarts at position ``prompt`` (``Restart``): the leaves
+that the reference names as holding a request's state apart from
+position get their prompt-end rows back, as a hybrid server's prefix
+cache hit would give them.
 
 The weights are the benchmark's own (``bench/reference``), made on the
 device from the seed in the program's parameter layout, so that the
@@ -32,6 +40,7 @@ import sys
 import tempfile
 import time
 from dataclasses import dataclass, field
+from functools import partial
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -61,6 +70,7 @@ class Step:
     nodes: int             # serving nodes during the step's decode
     rows: int              # requests decoded
     ctx: int               # positions each request attends over
+    restart_s: float = 0.0  # host seconds of the restart (``Restart``)
 
 
 @dataclass
@@ -89,6 +99,83 @@ def load_json(path: Path) -> Dict:
 def load_reader(name: str) -> Callable[[Run], Optional[float]]:
     """The reader of metric ``name``: ``bench/metrics/<name>.py``."""
     return importlib.import_module(f"bench.metrics.{name}").read
+
+
+# ---------------------------------------------------------------------------
+# a response's restart
+# ---------------------------------------------------------------------------
+
+def key_names(path) -> List[str]:
+    """A cache leaf's key path as names: what
+    ``repro.runtime.state.cache_batch_axis`` reads, and what a reference's
+    ``prefix_leaves`` is asked about."""
+    return [str(getattr(p, "key", getattr(p, "name", getattr(p, "idx", p))))
+            for p in path]
+
+
+class Restart:
+    """Each request's prompt-end state, written back into its row when a
+    response starts.
+
+    A response restarts at position ``prompt``.  K and V need nothing: the
+    positions past the prompt are written again and masked by position.
+    A leaf that holds a request's state apart from position (a recurrent
+    ``h``, a convolution window) keeps all that was decoded since the
+    prompt.  ``prefix_leaves(names)`` says which leaves those are; this
+    keeps their rows of the prefilled cache on each of ``devices`` and
+    writes the rows of the requests each node holds back into its shard,
+    one donated program per shard.  With no leaf named it keeps nothing,
+    and a restart touches no array and dispatches nothing."""
+
+    def __init__(self, cache, prefix_leaves: Callable[[List[str]], bool],
+                 devices: Sequence):
+        import jax
+        from repro.runtime.state import cache_batch_axes
+        flat = jax.tree_util.tree_flatten_with_path(cache)[0]
+        axes = jax.tree_util.tree_leaves(cache_batch_axes(cache))
+        self.which = [k for k, (path, _) in enumerate(flat)
+                      if prefix_leaves(key_names(path))]
+        leaves = [flat[k][1] for k in self.which]
+        self.snapshot = ({d: jax.device_put(leaves, d) for d in devices}
+                         if leaves else {})
+        self._write = jax.jit(
+            partial(_write_rows, tuple(axes[k] for k in self.which)),
+            donate_argnums=0)
+
+    def __call__(self, state) -> float:
+        """Restore the named leaves of every request in ``state`` (a
+        ``DeviceBucketedState``) where it is now; the host seconds."""
+        if not self.which:
+            return 0.0
+        import jax
+        t0 = time.perf_counter()
+        for i in state.node_ids():
+            rows = state.row_req[i]
+            if not (rows >= 0).any():
+                continue
+            dev = state.device_of(i)
+            leaves, tree = jax.tree_util.tree_flatten(state.shards[i])
+            new = self._write([leaves[k] for k in self.which],
+                              self.snapshot[dev],
+                              jax.device_put(rows.astype(np.int32), dev))
+            for k, leaf in zip(self.which, new):
+                leaves[k] = leaf
+            state.shards[i] = jax.tree_util.tree_unflatten(tree, leaves)
+        return time.perf_counter() - t0
+
+
+def _write_rows(axes, leaves, snapshot, rows):
+    """Each leaf with the snapshot's row of request ``rows[r]`` written
+    at row ``r`` (on its request axis); rows of -1 (padding) kept."""
+    import jax.numpy as jnp
+    out = []
+    for leaf, src, ax in zip(leaves, snapshot, axes):
+        cap = leaf.shape[ax]
+        vals = jnp.take(src, jnp.maximum(rows, 0), axis=ax)
+        at = jnp.where(rows >= 0, jnp.arange(cap), cap)
+        out.append(leaf.at[(slice(None),) * ax + (at,)].set(
+            vals.astype(leaf.dtype), mode="drop"))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -168,6 +255,8 @@ def run_cell(cfg: Dict, mix: traffic.Mix, seed: int, seconds: float,
     _, cache = jax.jit(prefill, static_argnums=1)(
         weights, pcfg, {"tokens": jnp.asarray(inputs.prompts)}, cache)
     settle("prefill", cache)
+    restart = Restart(cache, ref.prefix_leaves, devices)
+    settle("snapshot", restart.snapshot)
     req_bucket = route(mix.request_keys(), mix.buckets)
     backend = JaxBackend()
     ctl = ElasticController(
@@ -188,17 +277,19 @@ def run_cell(cfg: Dict, mix: traffic.Mix, seed: int, seconds: float,
     def layout():
         return tuple(tuple(iv) for iv in ctl.assign.intervals)
 
-    # warm-up: decode at the first topology, then whole event cycles
-    # until the layout repeats, so that every plan the window can meet
-    # has run (and compiled) once
+    # warm-up: restart and decode at the first topology, then whole event
+    # cycles until the layout repeats, so that every plan the window can
+    # meet has run (and compiled) once
     resp = 0
     feed = inputs.starts[resp][:, None]
+    restart(state)
     for s in range(2):
         feed = decode(feed, s)
     seen = {layout()}
     for _ in range(8 if mix.event_targets() else 0):
         for n in mix.event_targets():
             _do_resize(ctl, state, backend, n, -1, False)
+            restart(state)
             for s in range(2):
                 feed = decode(feed, s)
         if layout() in seen:
@@ -242,11 +333,16 @@ def run_cell(cfg: Dict, mix: traffic.Mix, seed: int, seconds: float,
                 for r in moved])
             run.events.append(info)
             nxt += 1
+        restart_s = 0.0
+        if s == 0:
+            with jax.profiler.TraceAnnotation("restart"):
+                restart_s = restart(state)
         with jax.profiler.TraceAnnotation("decode"):
             out = decode(feed, s)
         t1 = time.perf_counter() - w0
         with jax.profiler.TraceAnnotation("host"):
-            run.steps.append(Step(t0, t1, ev, ctl.n_nodes, B, P + s + 1))
+            run.steps.append(Step(t0, t1, ev, ctl.n_nodes, B, P + s + 1,
+                                  restart_s))
             if ev is not None:
                 run.events[ev].update(t0=t0, t1=t1)
             tokens[:, s] = out[:, 0]
@@ -280,7 +376,7 @@ def run_cell(cfg: Dict, mix: traffic.Mix, seed: int, seconds: float,
     routing_bad = sum(not e["routing_ok"] for e in run.events)
 
     # free the program's state before the reference runs
-    del state, placed, weights, ctl, backend, step_fn
+    del state, placed, weights, ctl, backend, step_fn, restart
     gc.collect()
 
     if trace:

@@ -5,6 +5,8 @@ the benchmark serves.
 program's options as it states, and ``check_program`` holds the program's
 configuration to it; the count functions give the operations
 and bytes a decode step and a state transfer need, from the sizes alone.
+``prefix_leaves`` names the cache leaves whose prompt-end rows a restarted
+response gets back: none, as K and V are kept by position.
 
 ``make_weights`` draws the weights from a key, on the device, in the type
 they are served in (bfloat16), laid out as the program's parameter tree
@@ -94,6 +96,15 @@ def check_program(pcfg, s: Dense) -> None:
     if got != want:
         raise ValueError(f"program config {pcfg.name} is {got}; the "
                          f"benchmark's file states {want}")
+
+
+def prefix_leaves(names) -> bool:
+    """Whether the cache leaf at key path ``names`` holds a request's
+    state apart from position, to be restored when a response restarts
+    (``bench.harness.Restart``).  No leaf of a dense decoder does: a
+    restart overwrites K and V past the prompt, and masks them by
+    position."""
+    return False
 
 
 # ---------------------------------------------------------------------------

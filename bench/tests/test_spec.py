@@ -143,6 +143,7 @@ def test_a_cell_in_no_list_gets_the_unlisted_metrics():
     ("olmo_1b.churn_m4096", CHURN),
     ("qwen25_3b.steady", STEADY),
     ("qwen25_3b.churn_4chip", CHURN),
+    ("olmo_1b.steady", STEADY),
 ])
 def test_cells_report_their_end_to_end_metrics(cell, names):
     assert reported(cell) == names
@@ -161,3 +162,18 @@ def test_the_four_chip_mix():
             mix.sample_responses) == (32, 1024, 64, 32, 32, 0.2, [1, 4],
                                       5.0, 2.5, 8)
     assert len(mix.events(SPEC["run_seconds"])) == 10
+
+
+def test_the_olmo_steady_mix():
+    """churn_m4096's requests and sizes at its first layout, no event."""
+    mix, churn = traffic.Mix.load("steady_m4096"), traffic.Mix.load(
+        "churn_m4096")
+    assert (mix.requests, mix.prompt, mix.gen, mix.buckets, mix.cap,
+            mix.tau, mix.nodes, mix.event_period_s, mix.event_phase_s,
+            mix.sample_responses) == (24, 1024, 64, 4096, 16, 0.2, [2],
+                                      None, None, 8)
+    assert mix.nodes == churn.nodes[:1] and all(
+        getattr(mix, k) == getattr(churn, k) for k in (
+            "requests", "prompt", "gen", "buckets", "cap", "tau",
+            "sample_responses"))
+    assert mix.event_targets() == [] and mix.events(SPEC["run_seconds"]) == []

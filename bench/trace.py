@@ -4,7 +4,7 @@ the operations that took most time, and idle gaps by what the host was
 doing.
 
 The harness wraps its window in a host span ``window`` and its calls into
-the program in spans ``decode``, ``resize`` and ``host``
+the program in spans ``restart``, ``decode``, ``resize`` and ``host``
 (``jax.profiler.TraceAnnotation``).  Device planes are the
 ``/device:TPU:<n>`` planes; busy time is the union of the intervals of
 their ``XLA Ops`` events, and a program's time is the sum of its
@@ -18,7 +18,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
-HOST_SPANS = ("decode", "resize", "host")
+HOST_SPANS = ("restart", "decode", "resize", "host")
 WINDOW = "window"
 
 Interval = Tuple[float, float]   # (start_ns, end_ns)
